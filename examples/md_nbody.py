@@ -34,6 +34,7 @@ from repro.checkpoint.store import Checkpointer
 from repro.core.api import TreecodeConfig, TreecodeSolver
 from repro.core.space import FreeSpace, PeriodicBox
 from repro.dynamics import Simulation
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
@@ -66,6 +67,7 @@ def main():
                     help="directory for trajectory checkpoints")
     ap.add_argument("--checkpoint-every", type=int, default=50)
     args = ap.parse_args()
+    enable_compile_cache()
 
     rng = np.random.default_rng(0)
     if args.box > 0:

@@ -22,9 +22,11 @@ import jax.numpy as jnp
 
 from repro.core.api import TreecodeConfig, TreecodeSolver
 from repro.core.direct import direct_sum
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     rng = np.random.default_rng(0)
     n = 20_000
     # random particles in the [-1,1]^3 cube, charges uniform on [-1,1]

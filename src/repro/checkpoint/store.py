@@ -26,9 +26,7 @@ _SEP = "::"
 
 
 def _flatten(tree):
-    from repro.compat import tree_flatten_with_path
-
-    flat, treedef = tree_flatten_with_path(tree)
+    flat, treedef = jax.tree.flatten_with_path(tree)
     items = {}
     for path, leaf in flat:
         key = _SEP.join(str(getattr(p, "key", getattr(p, "idx", p)))

@@ -66,6 +66,7 @@ import numpy as np
 from repro.core import eval as _eval
 from repro.core.potentials import Kernel, resolve_kernel
 from repro.core.space import FreeSpace, PeriodicBox, resolve_space
+from repro.kernels import ops
 from repro.obs import events as _events
 from repro.obs import trace as _trace
 from repro.obs.occupancy import static_occupancy as _static_occupancy
@@ -283,18 +284,30 @@ def _resolve_dtype(config: TreecodeConfig, arr: np.ndarray) -> np.dtype:
             # jax canonicalizes f64 to f32 when x64 is off; report the
             # precision the device will actually compute in.
             return np.dtype(np.float32)
-        return dt if dt in (np.dtype(np.float32), np.dtype(np.float64)) \
+        dt = dt if dt in (np.dtype(np.float32), np.dtype(np.float64)) \
             else np.dtype(np.float32)
-    if config.dtype == "float64" and not jax.config.jax_enable_x64:
+    elif config.dtype == "float64" and not jax.config.jax_enable_x64:
         raise ValueError(
             "TreecodeConfig(dtype='float64') requires x64 mode: set "
             "jax.config.update('jax_enable_x64', True) before planning")
-    return np.dtype(config.dtype)
+    else:
+        dt = np.dtype(config.dtype)
+    if dt == np.dtype(np.float64) \
+            and ops.resolve_backend(config.backend) == "pallas":
+        # Mosaic has no f64; refuse here rather than fail in the kernel
+        # compile or quietly evaluate on another backend.
+        raise ValueError(
+            f"float64 plans cannot run the Pallas TPU kernels (backend="
+            f"{config.backend!r} resolves to 'pallas' on this platform); "
+            "use float32, or pass backend='xla' for the XLA path")
+    return dt
 
 
 def lift_params(kernel: Kernel, dtype) -> object:
-    """Kernel defaults as traced-ready device arrays of the plan dtype."""
-    return jax.tree.map(lambda v: jnp.asarray(v, dtype=dtype),
+    """Kernel defaults as traced-ready device arrays of the plan dtype
+    (an explicit upload: plans are rebuilt inside transfer-guarded MD
+    loops)."""
+    return jax.tree.map(lambda v: jax.device_put(np.asarray(v, dtype)),
                         kernel.params)
 
 

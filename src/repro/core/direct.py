@@ -51,7 +51,8 @@ def direct_sum(
         g = kernel.pairwise(targets, s, params, space)
         # Padded sources may coincide at the origin with r2 > 0 against real
         # targets, so their contribution is removed via qs == 0.
-        return phi + g @ qs, None
+        pot = jnp.dot(g, qs, precision=jax.lax.Precision.HIGHEST)
+        return phi + pot, None
 
     phi0 = jnp.zeros(targets.shape[0], targets.dtype)
     phi, _ = jax.lax.scan(step, phi0, (src, q))
@@ -59,7 +60,7 @@ def direct_sum(
 
 
 def direct_oracle_f64(points, charges, *, kernel: Kernel, params=None,
-                      space=_FREE, chunk: int = 1024):
+                      space=_FREE, chunk: int = 1024, targets=None):
     """(phi, F) by float64 NumPy direct summation — the accuracy oracle.
 
     Host-side f64 regardless of the jax x64 mode, so refit/skin
@@ -68,9 +69,16 @@ def direct_oracle_f64(points, charges, *, kernel: Kernel, params=None,
     acceptance check of drift-budget v2). Supports the built-in
     coulomb/yukawa kernels (the analytic dG/dr2 is needed for forces);
     minimum-image displacements under a periodic `space`.
+
+    `targets` (optional index array) evaluates only those rows, against
+    every source: the sampled reference for sizes where all pairs are
+    out of reach. The result rows follow the order of `targets`.
     """
     x = np.asarray(points, np.float64)
     q = np.asarray(charges, np.float64)
+    rows = np.arange(x.shape[0]) if targets is None \
+        else np.asarray(targets, np.int64)
+    xt, qt = x[rows], q[rows]
     name = kernel.name
     if name == "yukawa":
         p = kernel.normalize_params(params) if params is not None \
@@ -79,33 +87,35 @@ def direct_oracle_f64(points, charges, *, kernel: Kernel, params=None,
     elif name != "coulomb":
         raise NotImplementedError(
             f"direct_oracle_f64 supports coulomb/yukawa, got {name!r}")
-    n = x.shape[0]
-    phi = np.zeros(n)
-    force = np.zeros((n, 3))
-    for s in range(0, n, chunk):
-        y = x[s:s + chunk]
-        d = x[:, None, :] - y[None, :, :]
-        if getattr(space, "periodic", False):
-            L = np.asarray(space.lengths)
-            d = d - L * np.round(d / L)
-        r2 = np.sum(d * d, axis=-1)
-        mask = r2 > 0.0
-        r2s = np.where(mask, r2, 1.0)
-        r = np.sqrt(r2s)
+    periodic = getattr(space, "periodic", False)
+    phi = np.zeros(len(rows))
+    force = np.zeros((len(rows), 3))
+    for s in range(0, x.shape[0], chunk):
+        y, qs = x[s:s + chunk], q[s:s + chunk]
+        # per-coordinate (targets, chunk) displacement planes
+        d = [xt[:, k:k + 1] - y[None, :, k] for k in range(3)]
+        if periodic:
+            d = [dk - Lk * np.round(dk / Lk)
+                 for dk, Lk in zip(d, space.lengths)]
+        r2 = d[0] * d[0] + d[1] * d[1] + d[2] * d[2]
+        hit = r2 == 0.0                      # the i == j term is excluded
+        r2[hit] = 1.0
+        rinv = 1.0 / np.sqrt(r2)
         if name == "coulomb":
-            g = 1.0 / r
-            dg = -0.5 / (r * r2s)            # dG/dr2 = -1/(2 r^3)
+            g = rinv
+            dgr = g * rinv * rinv            # -2 dG/dr2 = 1/r^3
         else:
-            e = np.exp(-kappa * r)
-            g = e / r
-            dg = -0.5 * e * (kappa * r + 1.0) / (r2s * r)
-        g = np.where(mask, g, 0.0)
-        dg = np.where(mask, dg, 0.0)
-        qs = q[s:s + chunk]
+            r = r2 * rinv
+            g = np.exp(-kappa * r) * rinv
+            dgr = g * (kappa * r + 1.0) * rinv * rinv
+        g[hit] = 0.0
+        dgr[hit] = 0.0
         phi += g @ qs
-        # grad_i phi = sum_j q_j * 2 * dG/dr2 * d_ij; F_i = -q_i * grad_i
-        force += np.einsum("nm,nmd->nd", 2.0 * dg * qs[None, :], d)
-    force *= -q[:, None]
+        # F_i = -q_i grad_i phi = q_i sum_j q_j (-2 dG/dr2) d_ij
+        w = dgr * qs
+        for k in range(3):
+            force[:, k] += np.einsum("nc,nc->n", w, d[k])
+    force *= qt[:, None]
     return phi, force
 
 

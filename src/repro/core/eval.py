@@ -316,7 +316,8 @@ def compute_qhat_hierarchical(arrays, q_sorted, *, degree, backend):
             rows.append(t / den[..., None])  # (P, n1_child, n1_parent)
         qc = qhat[children].reshape(-1, n1, n1, n1)
         contrib = jnp.einsum("pxa,pyb,pzc,pxyz->pabc",
-                             rows[0], rows[1], rows[2], qc)
+                             rows[0], rows[1], rows[2], qc,
+                             precision=jax.lax.Precision.HIGHEST)
         contrib = contrib.reshape(-1, n1 ** 3)
         qhat = qhat.at[parents].add(contrib)
     return qhat

@@ -124,7 +124,8 @@ class Kernel:
         spaces fall back to the difference form."""
         if getattr(space, "periodic", False):
             return self.pairwise(x, y, params, space)
-        xy = jnp.einsum("...nd,...md->...nm", x, y)
+        xy = jnp.einsum("...nd,...md->...nm", x, y,
+                        precision=jax.lax.Precision.HIGHEST)
         x2 = jnp.sum(x * x, axis=-1)[..., :, None]
         y2 = jnp.sum(y * y, axis=-1)[..., None, :]
         return self(jnp.maximum(x2 + y2 - 2.0 * xy, 0.0), params)

@@ -553,6 +553,9 @@ class _LazyStruct:
 
 
 def _materialize_tree(dev, node_lo, node_hi) -> Tree:
+    # The deferred device->host copy, explicit: a transfer guard refuses
+    # an implicit one (np.asarray of a device array) on an accelerator.
+    dev, node_lo, node_hi = jax.device_get((dev, node_lo, node_hi))
     depth = dev["depth"]
     srows = tuple(dev.get("sparse_rows", ()))
     occ = tuple(dev.get("sparse_occ", ()))
@@ -611,6 +614,7 @@ def _materialize_tree(dev, node_lo, node_hi) -> Tree:
 
 
 def _materialize_batches(dev) -> Batches:
+    dev = jax.device_get(dev)           # explicit, as in _materialize_tree
     nb = int(dev["n_batches"])
     lo = np.asarray(dev["b_lo"])[:nb]
     hi = np.asarray(dev["b_hi"])[:nb]

@@ -59,7 +59,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
 from repro.core import cheby
 from repro.core import eval as ceval
 from repro.core.api import TreecodeConfig, lift_params
@@ -69,6 +68,7 @@ from repro.core.potentials import Kernel
 from repro.core.tree import Tree
 from repro.distributed.rcb import RCB, rcb_partition
 from repro.kernels import ops
+from repro.launch.mesh import auto_mesh
 from repro.obs import events as _events
 from repro.obs import trace as _trace
 from repro.obs.occupancy import static_occupancy as _static_occ
@@ -332,9 +332,9 @@ def _build_spmd_fn(*, mesh, axis, degree, depth, perm_rounds, kernel,
     param_specs = jax.tree.unflatten(
         params_treedef, [rep] * params_treedef.num_leaves)
     return jax.jit(
-        compat.shard_map(spmd, mesh=mesh,
-                         in_specs=(specs, spec, param_specs),
-                         out_specs=spec),
+        jax.shard_map(spmd, mesh=mesh,
+                      in_specs=(specs, spec, param_specs),
+                      out_specs=spec, check_vma=False),
         donate_argnums=(1,) if donate else ())
 
 
@@ -387,12 +387,12 @@ class ShardedPlan:
     # Host build wall time per stage (ms): rcb / local_plans /
     # let_traversal / pad / commit — stats()["build_phases"].
     build_ms: Dict[str, float] = dataclasses.field(default_factory=dict)
-    # Strong per-instance refs to the fetched SPMD executables: plans
-    # must not lose their compiled traces to module-cache FIFO eviction
-    # (the module cache shares across plans; these pin for this plan).
-    _fn: Optional[object] = dataclasses.field(default=None, repr=False)
-    _fn_donating: Optional[object] = dataclasses.field(default=None,
-                                                      repr=False)
+    # Strong per-instance refs to the fetched SPMD executables, keyed by
+    # (donate, grad): plans must not lose their compiled traces to
+    # module-cache FIFO eviction (the module cache shares across plans;
+    # these pin for this plan).
+    _fns: Dict[Tuple[bool, bool], object] = dataclasses.field(
+        default_factory=dict, repr=False)
 
     # -- protocol aliases
     @property
@@ -560,7 +560,7 @@ class ShardedPlan:
         def stack(field, shape, value=0, recompute=None):
             outs = []
             for pl in plans:
-                a = np.asarray(pl.arrays[field])
+                a = np.asarray(jax.device_get(pl.arrays[field]))
                 if recompute is not None:
                     a = recompute(pl, a)
                 outs.append(ceval._pad2(a, shape, value))
@@ -596,8 +596,10 @@ class ShardedPlan:
             for pl in plans:
                 bg, bn = pl.arrays["bucket_gather"], pl.arrays["bucket_nodes"]
                 if lvl < len(bg):
-                    g = ceval._pad2(np.asarray(bg[lvl]), shape, -1)
-                    n = ceval._pad2(np.asarray(bn[lvl]), shape[:1], scratch)
+                    g = ceval._pad2(np.asarray(jax.device_get(bg[lvl])),
+                                    shape, -1)
+                    n = ceval._pad2(np.asarray(jax.device_get(bn[lvl])),
+                                    shape[:1], scratch)
                 else:
                     g = np.full(shape, -1, np.int32)
                     n = np.full(shape[:1], scratch, np.int32)
@@ -617,14 +619,14 @@ class ShardedPlan:
         _pad_span.__exit__(None, None, None)
         build_ms["pad"] = (time.perf_counter() - _t) * 1e3
         if mesh is None:
-            mesh = compat.make_mesh((nranks,), (axis,))
+            mesh = auto_mesh((nranks,), (axis,))
         sharded = jax.sharding.NamedSharding(
             mesh, jax.sharding.PartitionSpec(axis))
         replicated = jax.sharding.NamedSharding(
             mesh, jax.sharding.PartitionSpec())
         _t = time.perf_counter()
         with _trace.span("plan.commit"):
-            arrays = {k: jax.device_put(jnp.asarray(v), sharded)
+            arrays = {k: jax.device_put(v, sharded)
                       for k, v in arrays.items()}
         build_ms["commit"] = (time.perf_counter() - _t) * 1e3
 
@@ -645,9 +647,9 @@ class ShardedPlan:
                    padding_waste=waste, dtype=np.dtype(dtype),
                    capacities=caps,
                    rank_gather=jax.device_put(
-                       jnp.asarray(rank_gather, jnp.int32), sharded),
+                       rank_gather.astype(np.int32), sharded),
                    input_pos=jax.device_put(
-                       jnp.asarray(input_pos, jnp.int32), replicated),
+                       input_pos.astype(np.int32), replicated),
                    kernel_params=lift_params(kernel, np.dtype(dtype)),
                    mesh=mesh, axis=axis, mac_slack=mac_slack,
                    theta_slack=theta_slack, fold_slack=fold_slack,
@@ -657,7 +659,7 @@ class ShardedPlan:
     # device execution
     # ------------------------------------------------------------------
 
-    def _spmd_fn(self, donate: bool = False):
+    def _spmd_fn(self, donate: bool = False, grad: bool = False):
         """The shared jitted shard_map executable
         (arrays, q_rank, params) -> phi_rank.
 
@@ -671,25 +673,30 @@ class ShardedPlan:
         phi_rank has the identical (P, per_pad) shape/dtype, so XLA
         aliases the output into it (the `donate_charges` contract for
         iterative loops). The forces path must NOT use the donating
-        variant: it reuses one slab across three JVP evaluations."""
-        held = self._fn_donating if donate else self._fn
+        variant: it reuses one slab across three JVP evaluations.
+
+        `backend="auto"` resolves by the platform rule of
+        `ops.resolve_backend` (Pallas on a TPU). `grad=True` is the
+        variant the forces path differentiates: the Pallas kernels have
+        no JVP rule, so it runs `ops.autodiff_backend`, exactly as the
+        single-device forces do."""
+        variant = (donate, grad)
+        held = self._fns.get(variant)
         if held is not None:
             return held
         cfg = self.config
         if self.mesh is None:
-            self.mesh = compat.make_mesh((self.nranks,), (self.axis,))
+            self.mesh = auto_mesh((self.nranks,), (self.axis,))
+        resolve = ops.autodiff_backend if grad else ops.resolve_backend
         fn = _spmd_executable(
             mesh=self.mesh, axis=self.axis, degree=cfg.degree,
             depth=self.depth, perm_rounds=self.perm_rounds,
             kernel=self.kernel.stripped(), space=cfg.space,
-            backend="xla" if cfg.backend == "auto" else cfg.backend,
+            backend=resolve(cfg.backend),
             keys=tuple(sorted(self.arrays)),
             params_treedef=jax.tree.structure(self.kernel_params),
             donate=donate, theta=cfg.theta, skin=cfg.skin)
-        if donate:
-            self._fn_donating = fn
-        else:
-            self._fn = fn
+        self._fns[variant] = fn
         return fn
 
     def _rank_charges(self, charges) -> jnp.ndarray:
@@ -743,7 +750,7 @@ class ShardedPlan:
         w.r.t. the target slab (collectives are linear, so the tangents
         flow through all_gather/ppermute exactly). `weights` defaults to
         the charges (the physical force on charge q_i)."""
-        fn = self._spmd_fn()
+        fn = self._spmd_fn(grad=True)
         # weights first: with weights=None they default to the charges,
         # which must be read before anything could consume their buffer.
         w = jnp.asarray(charges if weights is None else weights,

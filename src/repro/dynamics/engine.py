@@ -202,8 +202,15 @@ class Simulation:
         # the primary cell at every rebuild, where the fresh tree splits
         # boundary-straddling clusters by construction.
         self.space = self.plan.config.space
+        # Jitted so the box lengths are compile-time constants: an eager
+        # wrap would upload them on every rebuild (an implicit transfer).
+        self._wrap = jax.jit(self.space.wrap)
         self.state: MDState = self.adapter.commit(initial_state(
             self.adapter.positions(), velocities, seed=seed, dtype=dtype))
+        # Same placement as the state, so diagnostics of a sharded run
+        # move nothing between devices.
+        self.charges, self.masses = self.adapter.commit(
+            (self.charges, self.masses))
         self._arrays = self.adapter.arrays
         # Reference for the per-step drift scalar: the positions of the
         # LAST force evaluation (where the budgets were refreshed from).
@@ -434,7 +441,7 @@ class Simulation:
         with _trace.span("md.rebuild_dispatch"):
             t0 = time.perf_counter()
             self._pending = self.adapter.rebuild_dispatch(
-                self.space.wrap(s1.x))
+                self._wrap(s1.x))
             self._pending_dispatch_ms = (time.perf_counter() - t0) * 1e3
         self._pending_cause = cause
 
@@ -457,7 +464,7 @@ class Simulation:
         # The shadow was built over wrapped positions: re-anchor the
         # live trajectory on the same wrapped coordinates (a lattice
         # shift, exactly as at a synchronous rebuild).
-        s1 = s1._replace(x=self.space.wrap(s1.x))
+        s1 = s1._replace(x=self._wrap(s1.x))
         if invalidated:
             # The shadow overflowed its budget: commit fell back to a
             # blocking growth loop and the new shapes force a retrace —
@@ -520,11 +527,12 @@ class Simulation:
                 "md.rebuild_device" if on_device else "md.rebuild_host")
             _rb_span.__enter__()
             _t0 = time.perf_counter()
-            s1 = s1._replace(x=self.space.wrap(s1.x))
+            s1 = s1._replace(x=self._wrap(s1.x))
             # Device rebuilds consume the live device positions — no
-            # host sync; only the needs vector crosses back.
+            # host sync; only the needs vector crosses back. Host
+            # rebuilds fetch the positions explicitly.
             invalidated = self.adapter.rebuild(
-                s1.x if on_device else np.asarray(s1.x))
+                s1.x if on_device else jax.device_get(s1.x))
             if invalidated:
                 # A capacity budget grew: the new shapes force a retrace
                 # (counted), deliberately — geometric growth bounds how
@@ -765,10 +773,10 @@ class Simulation:
             self.state._asdict(), step=step)
         self.state = self.adapter.commit(
             MDState(**{k: jnp.asarray(v) for k, v in tree.items()}))
-        self.state = self.state._replace(x=self.space.wrap(self.state.x))
+        self.state = self.state._replace(x=self._wrap(self.state.x))
         on_device = self.adapter.device_rebuild
         invalidated = self.adapter.rebuild(
-            self.state.x if on_device else np.asarray(self.state.x))
+            self.state.x if on_device else jax.device_get(self.state.x))
         if invalidated:
             self.capacity_growths += 1
             if self.adapter.recloses_on_rebuild:

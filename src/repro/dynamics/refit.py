@@ -408,7 +408,7 @@ class ShardedAdapter(PlanAdapter):
         return out
 
     def _bind(self):
-        self._fn = self.plan._spmd_fn()
+        self._fn = self.plan._spmd_fn(grad=True)
 
     def commit(self, tree):
         # Per-particle MD state is replicated over the mesh (the SPMD

@@ -69,6 +69,7 @@ def _pair_r2(tx, sy, mode: str, space=_FREE):
     fold is elementwise) with per-dimension folding."""
     if mode == "matmul" and not space.periodic:
         xy = jax.lax.dot_general(tx, sy, (((0,), (0,)), ((), ())),
+                                 precision=jax.lax.Precision.HIGHEST,
                                  preferred_element_type=tx.dtype)
         x2 = jnp.sum(tx * tx, axis=0)[:, None]
         y2 = jnp.sum(sy * sy, axis=0)[None, :]
@@ -91,55 +92,54 @@ def _read_params(par_ref, pspec):
     return unpack_params(lambda i: par_ref[0, i], pspec)
 
 
-def _body(idx_ref, par_ref, tgt_ref, src_ref, q_ref, out_ref, *,
-          kernel: Kernel, r2_mode: str = "diff", space=_FREE, pspec=None):
+def _slot_potential(idx_ref, par_ref, tgt_ref, src_ref, q_ref, *,
+                    kernel: Kernel, r2_mode: str, space, pspec, dtype):
+    """(1, NT) contribution of the current list slot, zero for a -1
+    sentinel. The charge contraction runs as a (1, m) x (NT, m)^T matmul
+    on the MXU, so every value stays 2-D (lane axis = particles)."""
     b = pl.program_id(0)
     s = pl.program_id(2)
+    r2 = _pair_r2(tgt_ref[0], src_ref[0], r2_mode, space)    # (NT, m)
+    g = kernel(r2, _read_params(par_ref, pspec))             # 0 at r2 == 0
+    pot = jax.lax.dot_general(
+        q_ref[0], g, dimension_numbers=(((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=dtype)                        # (1, NT)
+    valid = (idx_ref[b, s] >= 0).astype(dtype)
+    return valid * pot
 
-    @pl.when(s == 0)
+
+def _body(idx_ref, par_ref, tgt_ref, src_ref, q_ref, out_ref, **opts):
+    @pl.when(pl.program_id(2) == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    tx = tgt_ref[0]  # (3, NT)
-    sy = src_ref[0]  # (3, m)
-    r2 = _pair_r2(tx, sy, r2_mode, space)
-    g = kernel(r2, _read_params(par_ref, pspec))  # masked at r2 == 0
-    pot = jax.lax.dot_general(                    # (NT,) charge contraction
-        g, q_ref[0],
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=out_ref.dtype,
-    )
-    valid = (idx_ref[b, s] >= 0).astype(out_ref.dtype)
-    out_ref[0] += valid * pot
+    out_ref[0] += _slot_potential(idx_ref, par_ref, tgt_ref, src_ref, q_ref,
+                                  dtype=out_ref.dtype, **opts)
 
 
 def _body_kahan(idx_ref, par_ref, tgt_ref, src_ref, q_ref, out_ref,
-                comp_ref, *, kernel: Kernel, r2_mode: str = "diff",
-                space=_FREE, pspec=None):
+                comp_ref, **opts):
     # Compensated (Kahan) accumulation across list slots: pushes the f32
     # floor down ~1 digit for long interaction lists (beyond-paper accuracy
     # knob; see the hardware-adaptation table in DESIGN.md).
-    b = pl.program_id(0)
-    s = pl.program_id(2)
-
-    @pl.when(s == 0)
+    @pl.when(pl.program_id(2) == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
         comp_ref[...] = jnp.zeros_like(comp_ref)
 
-    tx = tgt_ref[0]
-    sy = src_ref[0]
-    g = kernel(_pair_r2(tx, sy, r2_mode, space),
-               _read_params(par_ref, pspec))
-    pot = jax.lax.dot_general(
-        g, q_ref[0], dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=out_ref.dtype,
-    )
-    valid = (idx_ref[b, s] >= 0).astype(out_ref.dtype)
-    y = valid * pot - comp_ref[0]
-    tsum = out_ref[0] + y
-    comp_ref[0] = (tsum - out_ref[0]) - y
+    y = _slot_potential(idx_ref, par_ref, tgt_ref, src_ref, q_ref,
+                        dtype=out_ref.dtype, **opts) - comp_ref[...]
+    acc = out_ref[0]
+    tsum = acc + y
+    comp_ref[...] = (tsum - acc) - y
     out_ref[0] = tsum
+
+
+#: SMEM bytes one call's scalar-prefetched interaction list may take. The
+#: v5e core has 1 MiB of SMEM and Mosaic pads the list's rows to 8;
+#: longer lists run as several calls (`batch_cluster_eval_pallas`).
+LIST_SMEM_BYTES = 256 * 1024
 
 
 def batch_cluster_eval_pallas(
@@ -149,6 +149,49 @@ def batch_cluster_eval_pallas(
     src_pts: jnp.ndarray,  # (C, 3, m) coordinate-major cluster points
     src_q: jnp.ndarray,    # (C, m) charges (0 = padding)
     kernel: Kernel,
+    **opts,
+) -> jnp.ndarray:
+    """phi (B, NB): potentials of every batch against its interaction list.
+
+    The list is split so each kernel call prefetches at most
+    `LIST_SMEM_BYTES` of it: slots in chunks summed afterwards (sentinels
+    contribute zero), batches in row chunks under `lax.map` (one compiled
+    kernel for all chunks)."""
+    idx = idx.astype(jnp.int32)
+    bsz, slots = idx.shape
+    max_slots = LIST_SMEM_BYTES // (8 * 4)
+    if slots > max_slots:
+        k = -(-slots // max_slots)
+        idx_s = jnp.pad(idx, ((0, 0), (0, k * max_slots - slots)),
+                        constant_values=-1)
+        idx_s = jnp.moveaxis(idx_s.reshape(bsz, k, max_slots), 1, 0)
+        return jax.lax.map(
+            lambda i: batch_cluster_eval_pallas(
+                i, par, tgt, src_pts, src_q, kernel, **opts),
+            idx_s).sum(axis=0)
+    rows = max(8, LIST_SMEM_BYTES // (4 * slots) // 8 * 8)
+    if bsz <= rows:
+        return _batch_cluster_call(idx, par, tgt, src_pts, src_q, kernel,
+                                   **opts)
+    nchunk = -(-bsz // rows)
+    pad = nchunk * rows - bsz
+    idx_c = jnp.pad(idx, ((0, pad), (0, 0)), constant_values=-1)
+    tgt_c = jnp.pad(tgt, ((0, pad), (0, 0), (0, 0)))
+    phi = jax.lax.map(
+        lambda a: _batch_cluster_call(a[0], par, a[1], src_pts, src_q,
+                                      kernel, **opts),
+        (idx_c.reshape(nchunk, rows, slots),
+         tgt_c.reshape(nchunk, rows, *tgt.shape[1:])))
+    return phi.reshape(nchunk * rows, -1)[:bsz]
+
+
+def _batch_cluster_call(
+    idx: jnp.ndarray,
+    par: jnp.ndarray,
+    tgt: jnp.ndarray,
+    src_pts: jnp.ndarray,
+    src_q: jnp.ndarray,
+    kernel: Kernel,
     *,
     pspec=None,            # static (treedef, shapes) for `par`
     space=_FREE,
@@ -157,7 +200,7 @@ def batch_cluster_eval_pallas(
     r2_mode: str = "diff",
     interpret: bool = False,
 ) -> jnp.ndarray:
-    """phi (B, NB): potentials of every batch against its interaction list."""
+    """One `pallas_call` over grid (batch, target-tile, list-slot)."""
     bsz, _, nb = tgt.shape
     _, _, m = src_pts.shape
     slots = idx.shape[1]
@@ -178,11 +221,11 @@ def batch_cluster_eval_pallas(
 
     def q_map(b, t, s, idx_ref, par_ref):
         del t, par_ref
-        return (jnp.maximum(idx_ref[b, s], 0), 0)
+        return (jnp.maximum(idx_ref[b, s], 0), 0, 0)
 
     def out_map(b, t, s, idx_ref, par_ref):
         del s, idx_ref, par_ref
-        return (b, t)
+        return (b, 0, t)
 
     kwargs = {}
     if not interpret:
@@ -203,15 +246,19 @@ def batch_cluster_eval_pallas(
         in_specs=[
             pl.BlockSpec((1, 3, nt), tgt_map),
             pl.BlockSpec((1, 3, m), src_map),
-            pl.BlockSpec((1, m), q_map),
+            pl.BlockSpec((1, 1, m), q_map),
         ],
-        out_specs=pl.BlockSpec((1, nt), out_map),
+        out_specs=pl.BlockSpec((1, 1, nt), out_map),
         scratch_shapes=scratch,
     )
-    return pl.pallas_call(
+    # Charges and potentials carry a unit middle axis so each block's last
+    # two dims are (1, full) -- Mosaic's tiling rule forbids a block of 1
+    # on the second-to-last axis of a 2-D array.
+    phi = pl.pallas_call(
         body,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((bsz, nb), tgt.dtype),
+        out_shape=jax.ShapeDtypeStruct((bsz, 1, nb), tgt.dtype),
         interpret=interpret,
         **kwargs,
-    )(idx.astype(jnp.int32), par.astype(tgt.dtype), tgt, src_pts, src_q)
+    )(idx, par.astype(tgt.dtype), tgt, src_pts, src_q[:, None, :])
+    return phi[:, 0, :]

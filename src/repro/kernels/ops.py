@@ -33,12 +33,18 @@ from repro.core.space import FREE as _FREE
 from repro.kernels import batch_cluster as _bc
 from repro.kernels import modified_charges as _mc
 
+# f32 contractions on the TPU default to one bf16 pass (~3 digits); the
+# treecode's accuracy contract needs full f32 (a no-op on the CPU).
+_HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def default_backend() -> str:
     return "pallas" if jax.default_backend() == "tpu" else "xla"
 
 
-def _resolve(backend: str) -> str:
+def resolve_backend(backend: str) -> str:
+    """The backend a config's `backend` names: "auto" follows the
+    platform (`default_backend`), anything else is taken as given."""
     return default_backend() if backend == "auto" else backend
 
 
@@ -46,7 +52,7 @@ def autodiff_backend(backend: str) -> str:
     """Backend to use under jvp/vjp: the Pallas kernel bodies have no AD
     rules, so derivative evaluations run the mathematically identical XLA
     path (same masking, same accumulation order up to reassociation)."""
-    resolved = _resolve(backend)
+    resolved = resolve_backend(backend)
     return "xla" if resolved in ("pallas", "pallas_interpret") else resolved
 
 
@@ -178,7 +184,7 @@ def batch_cluster_eval(
     r2_mode: str = "diff",
 ) -> jnp.ndarray:
     """phi (B, NB) = sum over list slots of batch-cluster interactions."""
-    backend = _resolve(backend)
+    backend = resolve_backend(backend)
     if backend in ("pallas", "pallas_interpret"):
         tgt_cm = jnp.swapaxes(tgt, -1, -2)          # (B, 3, NB)
         src_cm = jnp.swapaxes(src_pts, -1, -2)      # (C, 3, m)
@@ -211,7 +217,8 @@ def batch_cluster_eval(
               else kernel.pairwise)
         g = pw(tgt[:, None], pts, params, space)    # (B, S, NB, m)
         valid = (idx >= 0).astype(tgt.dtype)
-        return jnp.einsum("bsnm,bsm,bs->bn", g, qs, valid)
+        return jnp.einsum("bsnm,bsm,bs->bn", g, qs, valid,
+                          precision=_HIGHEST)
 
     # XLA path: scan over (batch-chunk, slot) to bound the (bc, NB, m)
     # pairwise intermediate. The chunk is rebalanced so padding never
@@ -238,7 +245,8 @@ def batch_cluster_eval(
                   else kernel.pairwise)
             g = pw(tgt_b, pts, params, space)       # (bc, NB, m)
             valid = (idx_s >= 0).astype(tgt_b.dtype)
-            return phi + jnp.einsum("bnm,bm,b->bn", g, qs, valid), None
+            return phi + jnp.einsum("bnm,bm,b->bn", g, qs, valid,
+                                    precision=_HIGHEST), None
 
         phi0 = jnp.zeros((batch_chunk, nb), tgt_b.dtype)
         phi, _ = jax.lax.scan(slot_step, phi0, idx_b.T)
@@ -277,7 +285,7 @@ def modified_charges(
     particle_tile: int = 512,
 ) -> jnp.ndarray:
     """q_hat (C, (n+1)^3), flattened k3-fastest (cluster_grid ordering)."""
-    backend = _resolve(backend)
+    backend = resolve_backend(backend)
     nodes = _cluster_nodes(lo, hi, degree)
     if backend in ("pallas", "pallas_interpret"):
         pts_cm = jnp.swapaxes(pts, -1, -2)  # (C, 3, m)
@@ -302,5 +310,5 @@ def modified_charges(
     qt = jnp.where(den != 0.0, q / jnp.where(den != 0.0, den, 1.0), 0.0)
     g2 = (t1[..., :, None] * t2[..., None, :]).reshape(*t1.shape[:-1], n1 * n1)
     r3 = t3 * qt[..., None]
-    qhat = jnp.einsum("cmp,cmk->cpk", g2, r3)
+    qhat = jnp.einsum("cmp,cmk->cpk", g2, r3, precision=_HIGHEST)
     return qhat.reshape(-1, n1 * n1 * n1)

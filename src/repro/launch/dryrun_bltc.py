@@ -22,13 +22,13 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
-from repro import compat  # noqa: E402
 from repro.core import cheby  # noqa: E402
 from repro.core import eval as ceval  # noqa: E402
 from repro.core.api import TreecodeConfig  # noqa: E402
 from repro.kernels import ops  # noqa: E402
 from repro.launch.dryrun import HBM_BW, ICI_BW, PEAK_FLOPS  # noqa: E402
 from repro.launch.hlo_analysis import analyze  # noqa: E402
+from repro.launch.mesh import auto_mesh  # noqa: E402
 
 
 def synthetic_shapes(nranks: int, n_per_rank: int, cfg: TreecodeConfig):
@@ -83,11 +83,11 @@ def lower_bltc(nranks: int, n_per_rank: int, multi_pod: bool):
     degree = cfg.degree
     axis = "data"
     if multi_pod:
-        mesh = compat.make_mesh((2, nranks // 2), ("pod", "data"))
+        mesh = auto_mesh((2, nranks // 2), ("pod", "data"))
         spec = P(("pod", "data"))
         axes = ("pod", "data")
     else:
-        mesh = compat.make_mesh((nranks,), ("data",))
+        mesh = auto_mesh((nranks,), ("data",))
         spec = P("data")
         axes = ("data",)
 
@@ -144,8 +144,9 @@ def lower_bltc(nranks: int, n_per_rank: int, multi_pod: bool):
         return phi.reshape(-1)[a["gather_index"]][None]
 
     specs = {k: spec for k in sds}
-    fn = jax.jit(compat.shard_map(
-        spmd, mesh=mesh, in_specs=(specs, spec), out_specs=spec))
+    fn = jax.jit(jax.shard_map(
+        spmd, mesh=mesh, in_specs=(specs, spec), out_specs=spec,
+        check_vma=False))
     q_sds = jax.ShapeDtypeStruct((nranks, n_per_rank), jnp.float32)
     t0 = time.time()
     lowered = fn.lower(sds, q_sds)

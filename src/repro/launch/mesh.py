@@ -11,17 +11,21 @@ from __future__ import annotations
 
 import jax
 
-from repro.compat import make_mesh
+
+def auto_mesh(shape, axes):
+    """`jax.make_mesh` with Auto axis types (its default is Explicit)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_host_mesh(model_axis: int = 1):
     """Small mesh over whatever devices exist (CPU tests / examples)."""
     n = jax.device_count()
     data = n // model_axis
-    return make_mesh((data, model_axis), ("data", "model"))
+    return auto_mesh((data, model_axis), ("data", "model"))

@@ -60,7 +60,10 @@ def main(argv=None):
 
     from repro import obs
     from repro.core.api import TreecodeConfig
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.serve import ServeFrontend
+
+    enable_compile_cache()
 
     if args.trace:
         obs.enable()

@@ -12,7 +12,7 @@ Entry points recognized (the forms this repo actually uses):
   ``@_trace.traced(...)`` (span-wrapped device helpers are held to the
   same trace-safety rules: they run inside jit regions by convention);
 - call forms: ``jax.jit(fn, ...)``, ``vmap(fn)``, ``shard_map(fn,
-  mesh=...)`` (including the ``compat.shard_map`` wrapper),
+  mesh=...)``,
   ``pl.pallas_call(kernel, ...)`` — ``fn`` resolved lexically (local
   defs of enclosing functions, then module scope, then imports);
 - bindings: ``execute = jax.jit(_execute_impl, static_argnames=...,

@@ -1,18 +1,5 @@
-import sys
-
 import numpy as np
 import pytest
-
-try:  # pragma: no cover - exercised only where hypothesis is installed
-    import hypothesis  # noqa: F401
-except ModuleNotFoundError:
-    # Containers without pip access run against a deterministic shim that
-    # implements the subset of hypothesis the suite uses (see the module
-    # docstring). CI installs the real package from requirements.txt.
-    import _hypothesis_shim as _shim
-
-    sys.modules["hypothesis"] = _shim
-    sys.modules["hypothesis.strategies"] = _shim.strategies
 
 
 @pytest.fixture

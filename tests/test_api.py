@@ -142,6 +142,27 @@ def test_dtype_float64_requires_x64_mode():
         solver.plan(pts)
 
 
+@pytest.mark.parametrize("dtype,backend", [
+    ("float64", "pallas"), ("auto", "pallas"), ("float64", "auto")])
+def test_float64_refused_where_pallas_runs(x64, monkeypatch, dtype,
+                                           backend):
+    """Mosaic has no f64: a float64 plan whose backend resolves to the
+    Pallas TPU kernels fails at plan time with a clear error instead of
+    in the kernel compile (or on another backend). "auto" resolves to
+    Pallas on a TPU, which the platform rule is steered to here."""
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops, "default_backend", lambda: "pallas")
+    pts, _ = _particles(7, 100)      # f64 inputs
+    solver = TreecodeSolver(TreecodeConfig(dtype=dtype, backend=backend))
+    with pytest.raises(ValueError, match="float64 plans cannot run"):
+        solver.plan(pts)
+    # the same config on the XLA backend plans in f64
+    plan = TreecodeSolver(TreecodeConfig(
+        dtype=dtype, backend="xla", degree=3, leaf_size=32)).plan(pts)
+    assert plan.stats()["dtype"] == "float64"
+
+
 # ---------------------------------------------------------------------------
 # forces
 # ---------------------------------------------------------------------------

@@ -10,10 +10,8 @@ forces the hybrid sparse levels (adaptive depths 6-8, beyond the dense
 SPLIT_DEPTH) and checks both coverage and float64-oracle force
 equivalence there, in free and periodic space.
 
-Runs against real `hypothesis` when installed (CI pins the examples
-with ``derandomize=True``); containers without it use the seeded shim
-in `_hypothesis_shim.py` (registered by conftest), so the draws are
-deterministic either way.
+The examples are pinned with ``derandomize=True``, so the draws are
+deterministic.
 """
 import numpy as np
 from hypothesis import given, settings

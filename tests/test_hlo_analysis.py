@@ -4,6 +4,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.launch.hlo_analysis import analyze, parse_hlo
+from repro.launch.mesh import auto_mesh
 
 
 def _compile(f, *args):
@@ -54,9 +55,7 @@ def test_elementwise_flops_counted():
 def test_collectives_counted_with_trips(tmp_path):
     """psum inside a scanned body over a 1-device mesh still appears in
     HLO as all-reduce; the analyzer multiplies by the trip count."""
-    from repro import compat
-
-    mesh = compat.make_mesh((1,), ("d",))
+    mesh = auto_mesh((1,), ("d",))
 
     def f(xs):
         def body(c, x):
@@ -64,9 +63,10 @@ def test_collectives_counted_with_trips(tmp_path):
         out, _ = jax.lax.scan(body, jnp.zeros(xs.shape[1:]), xs)
         return out
 
-    sm = compat.shard_map(f, mesh=mesh,
-                          in_specs=jax.sharding.PartitionSpec(),
-                          out_specs=jax.sharding.PartitionSpec())
+    sm = jax.shard_map(f, mesh=mesh,
+                       in_specs=jax.sharding.PartitionSpec(),
+                       out_specs=jax.sharding.PartitionSpec(),
+                       check_vma=False)
     c = jax.jit(sm).lower(jnp.zeros((6, 8))).compile()
     t = analyze(c.as_text())
     total = sum(v["count"] for v in t.collectives.values())

@@ -61,6 +61,24 @@ def test_batch_cluster_eval_kahan(rng):
                                rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("kahan", [False, True])
+def test_batch_cluster_split_list_matches_ref(rng, monkeypatch, kahan):
+    """Lists longer than one call's SMEM budget run as slot chunks and
+    batch-row chunks; a tiny budget here forces both splits, with
+    padding on each axis."""
+    from repro.kernels import batch_cluster
+
+    monkeypatch.setattr(batch_cluster, "LIST_SMEM_BYTES", 8 * 4 * 4)
+    idx, tgt, src, q = _case(rng, 19, 10, 16, 6, 24, np.float32)
+    kern = yukawa(0.5)
+    want = ref.ref_batch_cluster_eval(idx, tgt, src, q, kern)
+    got = ops.batch_cluster_eval(idx, tgt, src, q, kernel=kern,
+                                 backend="pallas_interpret", target_tile=16,
+                                 kahan=kahan)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-4, atol=2e-4)
+
+
 def test_batch_cluster_all_empty_slots(rng):
     idx = jnp.full((2, 3), -1, jnp.int32)
     _, tgt, src, q = _case(rng, 2, 3, 8, 2, 8, np.float32)

@@ -386,7 +386,7 @@ def test_resolver_call_graph_propagation():
 def test_resolver_vmap_and_shard_map_call_forms():
     mod, _ = _resolve("""
         import jax
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         def body(x):
             return x + 1.0

@@ -125,3 +125,23 @@ def test_padding_waste_reported():
                                            backend="xla"))
     plan = solver.plan(pts, pts)
     assert 0.0 <= plan.padding_waste < 0.9
+
+
+@pytest.mark.parametrize("kernel,space", [
+    (coulomb(), None), (yukawa(0.8), (2.0, 2.0, 2.0))])
+def test_direct_oracle_sampled_rows_match_all_pairs(kernel, space):
+    """The sampled f64 oracle (a subset of targets against every source)
+    equals the all-pairs oracle on those rows, in the order given."""
+    from repro.core.direct import direct_oracle_f64
+    from repro.core.space import FREE, PeriodicBox
+
+    rng = np.random.default_rng(11)
+    x = rng.uniform(0, 2, (700, 3))
+    q = rng.uniform(-1, 1, 700)
+    sp = FREE if space is None else PeriodicBox(space)
+    phi, f = direct_oracle_f64(x, q, kernel=kernel, space=sp, chunk=128)
+    rows = rng.choice(700, 57, replace=False)
+    phi_s, f_s = direct_oracle_f64(x, q, kernel=kernel, space=sp,
+                                   chunk=96, targets=rows)
+    np.testing.assert_allclose(phi_s, phi[rows], rtol=1e-12)
+    np.testing.assert_allclose(f_s, f[rows], rtol=1e-10, atol=1e-12)
