@@ -372,14 +372,16 @@ class SingleDevicePlan:
         fn = (_eval.execute_donating if self.config.donate_charges
               else _eval.execute)
         with _trace.span("eval.execute"):
-            out, _ = _events.log_compiles(
-                "execute_donating" if self.config.donate_charges
-                else "execute",
-                fn, self.inner.arrays, self._charges(charges),
-                self._params(kernel_params),
-                key=lambda: hash(_eval.plan_signature(self.inner)),
-                site="SingleDevicePlan.execute", owner="core.eval",
-                **self.config.exec_opts(self.kernel))
+            q = self._charges(charges)
+            params = self._params(kernel_params)
+            with _trace.span("eval.dispatch"):
+                out, _ = _events.log_compiles(
+                    "execute_donating" if self.config.donate_charges
+                    else "execute",
+                    fn, self.inner.arrays, q, params,
+                    key=lambda: hash(_eval.plan_signature(self.inner)),
+                    site="SingleDevicePlan.execute", owner="core.eval",
+                    **self.config.exec_opts(self.kernel))
         return out
 
     def potential_and_forces(self, charges, weights=None,
@@ -443,8 +445,10 @@ class SingleDevicePlan:
 
     def stats(self) -> dict:
         """Geometry / cost counters: tree and batch sizes, padding
-        waste, the MAC slack (refit drift budget), and — when
-        capacity-padded — the `Capacities` budget the arrays occupy."""
+        waste, the MAC slack (refit drift budget), the pair evaluations
+        the `batch_cluster` kernels launch against those they need
+        (`kernel_work`), and — when capacity-padded — the `Capacities`
+        budget the arrays occupy."""
         tree = self.inner.tree
         caps = self.inner.capacities
         return dict(
@@ -469,6 +473,7 @@ class SingleDevicePlan:
             # padded-vs-real utilization of the packed arrays.
             build_phases=dict(self.inner.build_ms),
             occupancy=_static_occupancy(self.inner),
+            kernel_work=_eval.kernel_work(self.inner, self.config.degree),
             **({"capacities": dataclasses.asdict(caps)} if caps else {}),
         )
 

@@ -31,6 +31,7 @@ from repro.core.interaction import build_interaction_lists
 from repro.core.potentials import Kernel
 from repro.core.space import FREE as _FREE
 from repro.core.tree import Batches, Tree, build_batches, build_tree
+from repro.kernels import batch_cluster as _bc
 from repro.kernels import ops
 from repro.obs import trace as _trace
 
@@ -130,9 +131,27 @@ def _prepare_plan_timed(targets, sources, *, theta, degree, leaf_size,
                                         skin=skin)
     t2 = time.perf_counter()
     build_ms["interaction_lists"] = (t2 - t1) * 1e3
-    _pack_span = _trace.span("plan.pack")
-    _pack_span.__enter__()
+    with _trace.span("plan.pack"):
+        host = _pack_host(targets, sources, tree, batches, lists, dtype)
+    # Every host-to-device copy of the plan, in one place.
+    with _trace.span("plan.copy"):
+        arrays = jax.tree.map(jnp.asarray, host)
+    meta = (degree,)
+    build_ms["pack"] = (time.perf_counter() - t2) * 1e3
+    return Plan(
+        arrays=arrays, meta=meta, tree=tree, batches=batches,
+        padding_waste=float(lists.padding_waste),
+        num_targets=targets.shape[0], num_sources=sources.shape[0],
+        mac_slack=float(lists.mac_slack),
+        theta_slack=float(lists.theta_slack),
+        fold_slack=float(lists.fold_slack),
+        skin=float(skin), space=space, build_ms=build_ms,
+    )
 
+
+def _pack_host(targets, sources, tree, batches, lists, dtype) -> dict:
+    """The plan's arrays as NumPy: the padded target batches, gather
+    tables, interaction lists and per-level cluster buckets."""
     nb_pad = _round_up(batches.max_count)
     nl_pad = _round_up(tree.max_leaf_count)
     a_pad = _round_up(lists.approx.shape[1])
@@ -191,41 +210,29 @@ def _prepare_plan_timed(targets, sources, *, theta, degree, leaf_size,
     for node_ids in tree.levels():
         m_pad = _round_pow2(int(tree.count[node_ids].max()))
         g = _range_table(tree.start[node_ids], tree.count[node_ids], m_pad)
-        bucket_gather.append(jnp.asarray(g, jnp.int32))
-        bucket_nodes.append(jnp.asarray(node_ids, jnp.int32))
+        bucket_gather.append(g.astype(np.int32))
+        bucket_nodes.append(np.asarray(node_ids, np.int32))
 
-    arrays = dict(
-        src_sorted=jnp.asarray(sources[tree.perm]),
-        src_perm=jnp.asarray(tree.perm, jnp.int32),
-        tgt_batched=jnp.asarray(tgt_b),
-        gather_index=jnp.asarray(gather_index),
-        leaf_gather=jnp.asarray(leaf_gather, jnp.int32),
-        node_lo=jnp.asarray(tree.lo.astype(dtype)),
-        node_hi=jnp.asarray(tree.hi.astype(dtype)),
-        approx_idx=jnp.asarray(approx_idx),
-        direct_idx=jnp.asarray(direct_idx),
+    return dict(
+        src_sorted=sources[tree.perm],
+        src_perm=np.asarray(tree.perm, np.int32),
+        tgt_batched=tgt_b,
+        gather_index=gather_index,
+        leaf_gather=leaf_gather.astype(np.int32),
+        node_lo=tree.lo.astype(dtype),
+        node_hi=tree.hi.astype(dtype),
+        approx_idx=approx_idx,
+        direct_idx=direct_idx,
         # Verlet-skin dual lists + the target validity mask feeding the
         # runtime MAC gate (all--1 / all-False beyond the real rows).
-        approx_skin=jnp.asarray(approx_skin),
-        skin_direct=jnp.asarray(skin_direct),
-        skin_direct_node=jnp.asarray(skin_direct_node),
-        tgt_mask=jnp.asarray(tgt_mask),
+        approx_skin=approx_skin,
+        skin_direct=skin_direct,
+        skin_direct_node=skin_direct_node,
+        tgt_mask=tgt_mask,
         bucket_gather=tuple(bucket_gather),
         bucket_nodes=tuple(bucket_nodes),
         # Hierarchical (upward-pass) precompute tables, built lazily.
-        parent_of=jnp.asarray(tree.parent, jnp.int32),
-    )
-    meta = (degree,)
-    _pack_span.__exit__(None, None, None)
-    build_ms["pack"] = (time.perf_counter() - t2) * 1e3
-    return Plan(
-        arrays=arrays, meta=meta, tree=tree, batches=batches,
-        padding_waste=float(lists.padding_waste),
-        num_targets=targets.shape[0], num_sources=sources.shape[0],
-        mac_slack=float(lists.mac_slack),
-        theta_slack=float(lists.theta_slack),
-        fold_slack=float(lists.fold_slack),
-        skin=float(skin), space=space, build_ms=build_ms,
+        parent_of=np.asarray(tree.parent, np.int32),
     )
 
 
@@ -382,16 +389,23 @@ def _execute_impl(
 
     `theta`/`skin` are static: with ``skin > 0`` the Verlet-skin dual
     lists are routed by the runtime MAC gate (`_skin_routed_lists`)
-    before the kernels run."""
+    before the kernels run.
+
+    The three kernel sites run under named scopes, which a profiler
+    trace carries in each device operation's metadata:
+    ``bltc.modified_charges``, ``bltc.approx`` and ``bltc.direct``; the
+    two `batch_cluster` kernels are also named by site (``bltc_approx``,
+    ``bltc_direct``), which names their operations in the trace."""
     q_sorted = charges[arrays["src_perm"]]
-    if precompute == "direct":
-        qhat = compute_qhat_direct(
-            arrays, q_sorted, degree=degree, backend=backend)
-    elif precompute == "hierarchical":
-        qhat = compute_qhat_hierarchical(
-            arrays, q_sorted, degree=degree, backend=backend)
-    else:
-        raise ValueError(f"unknown precompute {precompute!r}")
+    with jax.named_scope("bltc.modified_charges"):
+        if precompute == "direct":
+            qhat = compute_qhat_direct(
+                arrays, q_sorted, degree=degree, backend=backend)
+        elif precompute == "hierarchical":
+            qhat = compute_qhat_hierarchical(
+                arrays, q_sorted, degree=degree, backend=backend)
+        else:
+            raise ValueError(f"unknown precompute {precompute!r}")
 
     grids = cheby.cluster_grid(arrays["node_lo"], arrays["node_hi"], degree)
     tgt = arrays["tgt_batched"]
@@ -401,16 +415,19 @@ def _execute_impl(
         approx_idx, direct_idx = arrays["approx_idx"], arrays["direct_idx"]
     # The approximation kernel may use the MXU matmul form of r^2: the MAC
     # guarantees target/cluster separation, so no cancellation risk there.
-    phi_a = ops.batch_cluster_eval(
-        approx_idx, tgt, grids, qhat, params,
-        kernel=kernel, space=space, backend=backend, kahan=kahan,
-        r2_mode=approx_r2)
+    with jax.named_scope("bltc.approx"):
+        phi_a = ops.batch_cluster_eval(
+            approx_idx, tgt, grids, qhat, params,
+            kernel=kernel, space=space, backend=backend, kahan=kahan,
+            r2_mode=approx_r2, name="bltc_approx")
 
     leaf_pts, leaf_q = _gathered(
         arrays["src_sorted"], q_sorted, arrays["leaf_gather"])
-    phi_d = ops.batch_cluster_eval(
-        direct_idx, tgt, leaf_pts, leaf_q, params,
-        kernel=kernel, space=space, backend=backend, kahan=kahan)
+    with jax.named_scope("bltc.direct"):
+        phi_d = ops.batch_cluster_eval(
+            direct_idx, tgt, leaf_pts, leaf_q, params,
+            kernel=kernel, space=space, backend=backend, kahan=kahan,
+            name="bltc_direct")
 
     phi = (phi_a + phi_d).reshape(-1)
     return phi[arrays["gather_index"]]
@@ -911,8 +928,10 @@ def _pad_plan_impl(plan: Plan, caps: Capacities) -> Plan:
             f"sources) exceeds the point budget ({caps.num_targets} / "
             f"{caps.num_sources}); grow via grown_to_fit_need with "
             f"explicit num_targets/num_sources keys")
-    a = {k: np.asarray(v) for k, v in plan.arrays.items()
-         if not isinstance(v, tuple)}
+    # The plan's arrays back on the host, to be re-padded there.
+    with _trace.span("plan.copy"):
+        a = {k: (tuple(np.asarray(x) for x in v) if isinstance(v, tuple)
+                 else np.asarray(v)) for k, v in plan.arrays.items()}
     scratch = caps.scratch_node
 
     nb_old = a["tgt_batched"].shape[1]
@@ -969,44 +988,72 @@ def _pad_plan_impl(plan: Plan, caps: Capacities) -> Plan:
             a["src_perm"],
             np.arange(ns, caps.num_sources, dtype=np.int32)])
 
-    bg_old = plan.arrays["bucket_gather"]
-    bn_old = plan.arrays["bucket_nodes"]
+    bg_old = a["bucket_gather"]
+    bn_old = a["bucket_nodes"]
     bgs, bns = [], []
     for lvl in range(caps.depth):
         shape = (caps.bucket_rows[lvl], caps.bucket_widths[lvl])
         if lvl < len(bg_old):
-            g = _pad2(np.asarray(bg_old[lvl]), shape, -1)
-            n = _pad2(np.asarray(bn_old[lvl]), shape[:1], scratch)
+            g = _pad2(bg_old[lvl], shape, -1)
+            n = _pad2(bn_old[lvl], shape[:1], scratch)
         else:
             g = np.full(shape, -1, np.int32)
             n = np.full(shape[:1], scratch, np.int32)
-        bgs.append(jnp.asarray(g, jnp.int32))
-        bns.append(jnp.asarray(n, jnp.int32))
+        bgs.append(np.asarray(g, np.int32))
+        bns.append(np.asarray(n, np.int32))
     out["bucket_gather"] = tuple(bgs)
     out["bucket_nodes"] = tuple(bns)
 
-    if "upward_pairs" in plan.arrays:
-        out["leaf_node_ids"] = _pad2(
-            np.asarray(plan.arrays["leaf_node_ids"]),
-            (caps.num_leaves,), scratch)
-        up_old = plan.arrays["upward_pairs"]
+    if "upward_pairs" in a:
+        out["leaf_node_ids"] = _pad2(a["leaf_node_ids"],
+                                     (caps.num_leaves,), scratch)
+        up_old = a["upward_pairs"]
         ups = []
         for slot in range(len(caps.upward_rows)):
             shape = (caps.upward_rows[slot], 2)
             if slot < len(up_old):
-                p = _pad2(np.asarray(up_old[slot]), shape, scratch)
+                p = _pad2(up_old[slot], shape, scratch)
             else:
                 p = np.full(shape, scratch, np.int32)
-            ups.append(jnp.asarray(p, jnp.int32))
+            ups.append(np.asarray(p, np.int32))
         out["upward_pairs"] = tuple(ups)
 
-    arrays = {k: (v if isinstance(v, tuple) else jnp.asarray(v))
-              for k, v in out.items()}
+    with _trace.span("plan.copy"):
+        arrays = jax.tree.map(jnp.asarray, out)
     build_ms = dict(plan.build_ms)
     build_ms["pad"] = build_ms.get("pad", 0.0) \
         + (time.perf_counter() - _t_pad) * 1e3
     return dataclasses.replace(plan, arrays=arrays, capacities=caps,
                                scratch_node=scratch, build_ms=build_ms)
+
+
+def kernel_work(plan: Plan, degree: int) -> dict:
+    """Pair evaluations the two `batch_cluster` lists of `plan` launch
+    and need, ``{"approx"|"direct": {"launched", "useful"}}``
+    (`kernels.batch_cluster.kernel_work`). Host arithmetic: fetches the
+    lists and masks once, off the hot path.
+
+    With a Verlet skin the direct list runs with its skin-direct columns
+    appended (`_skin_routed_lists`); they are launched, and at the
+    build's own geometry the runtime gate leaves every one of them empty.
+    """
+    keys = ("approx_idx", "direct_idx", "tgt_mask", "leaf_gather")
+    a = jax.device_get({k: plan.arrays[k] for k in keys})
+    direct = a["direct_idx"]
+    if plan.skin > 0.0:
+        skin_cols = plan.arrays["skin_direct"].shape[1]
+        direct = np.pad(direct, ((0, 0), (0, skin_cols)),
+                        constant_values=-1)
+    tgt = a["tgt_mask"].sum(1)
+    width = a["tgt_mask"].shape[1]
+    k3 = (degree + 1) ** 3
+    points = np.full(plan.arrays["node_lo"].shape[0], k3)
+    return dict(
+        approx=_bc.kernel_work(a["approx_idx"], tgt, points,
+                               target_width=width, source_width=k3),
+        direct=_bc.kernel_work(direct, tgt, (a["leaf_gather"] >= 0).sum(1),
+                               target_width=width,
+                               source_width=a["leaf_gather"].shape[1]))
 
 
 def plan_signature(plan: Plan) -> Tuple:

@@ -56,7 +56,6 @@ import numpy as np
 
 from repro.core.space import FreeSpace
 from repro.core.tree import Batches, Tree
-from repro.obs.trace import traced as _traced
 
 # Margin shrink rates per unit of particle drift (DESIGN.md §4): each box
 # endpoint moves <= drift per coordinate, so each half-diagonal grows and
@@ -175,7 +174,6 @@ def scaled_mac_slack(theta: float, theta_slack: float,
     return float(min(theta_slack, fold_slack * scale))
 
 
-@_traced("interaction.build_lists")
 def build_interaction_lists(
     tree: Tree,
     batches: Batches,

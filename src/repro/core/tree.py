@@ -21,8 +21,6 @@ import dataclasses
 
 import numpy as np
 
-from repro.obs.trace import traced as _traced
-
 _SPLIT_RATIO = 1.0 / np.sqrt(2.0)
 
 
@@ -75,7 +73,6 @@ class Tree:
         return np.arange(i0, i1)
 
 
-@_traced("tree.build_tree")
 def build_tree(points: np.ndarray, leaf_size: int) -> Tree:
     """Build the source tree (or, with leaf_size=N_B, the target batches).
 
@@ -167,7 +164,6 @@ def build_tree(points: np.ndarray, leaf_size: int) -> Tree:
     )
 
 
-@_traced("tree.refit_tree")
 def refit_tree(tree: Tree, points: np.ndarray) -> Tree:
     """Recompute box geometry for moved particles under a FIXED topology.
 

@@ -49,6 +49,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -142,6 +143,46 @@ def _body_kahan(idx_ref, par_ref, tgt_ref, src_ref, q_ref, out_ref,
 LIST_SMEM_BYTES = 256 * 1024
 
 
+def list_split(bsz: int, slots: int):
+    """How `batch_cluster_eval_pallas` splits a (bsz, slots) list so each
+    call prefetches at most `LIST_SMEM_BYTES` of it.
+
+    Returns (slot_chunks, chunk_slots, row_chunks, rows): the list runs
+    as `slot_chunks` chunks of `chunk_slots` slots (sentinel-padded),
+    each as `row_chunks` calls over `rows` batch rows (one call over all
+    `bsz` rows when they fit, else padded to `row_chunks * rows`)."""
+    max_slots = LIST_SMEM_BYTES // (8 * 4)
+    slot_chunks = -(-slots // max_slots) if slots > max_slots else 1
+    chunk_slots = max_slots if slots > max_slots else slots
+    rows = max(8, LIST_SMEM_BYTES // (4 * chunk_slots) // 8 * 8)
+    if bsz <= rows:
+        return slot_chunks, chunk_slots, 1, bsz
+    return slot_chunks, chunk_slots, -(-bsz // rows), rows
+
+
+def kernel_work(idx, tgt_counts, src_counts, *, target_width: int,
+                source_width: int, target_tile: int = 256) -> dict:
+    """Pair evaluations of one `batch_cluster` list, launched and useful.
+
+    `idx` (B, S) is the list as the kernel runs it (-1 = empty slot),
+    `tgt_counts` (B,) the real targets of each batch row, `src_counts`
+    (C,) the real sources of each cluster id. `launched`
+    counts what the Pallas grids execute: rows x target lanes (padded
+    to `target_tile`) x slots x `source_width`, over every chunk of
+    `list_split`; sentinel slots and padding included. `useful` counts
+    real targets x real sources over the non-sentinel slots."""
+    idx = np.asarray(idx)
+    bsz, slots = idx.shape
+    slot_chunks, chunk_slots, row_chunks, rows = list_split(bsz, slots)
+    lanes = -(-target_width // target_tile) * target_tile
+    launched = (slot_chunks * chunk_slots * row_chunks * rows * lanes
+                * source_width)
+    src = np.asarray(src_counts, np.int64)
+    per_row = np.where(idx >= 0, src[np.maximum(idx, 0)], 0).sum(1)
+    useful = int((np.asarray(tgt_counts, np.int64) * per_row).sum())
+    return dict(launched=int(launched), useful=useful)
+
+
 def batch_cluster_eval_pallas(
     idx: jnp.ndarray,      # (B, S) int32 cluster ids, -1 = empty
     par: jnp.ndarray,      # (1, P) packed kernel parameter values
@@ -159,21 +200,18 @@ def batch_cluster_eval_pallas(
     kernel for all chunks)."""
     idx = idx.astype(jnp.int32)
     bsz, slots = idx.shape
-    max_slots = LIST_SMEM_BYTES // (8 * 4)
-    if slots > max_slots:
-        k = -(-slots // max_slots)
-        idx_s = jnp.pad(idx, ((0, 0), (0, k * max_slots - slots)),
+    k, chunk_slots, nchunk, rows = list_split(bsz, slots)
+    if k > 1:
+        idx_s = jnp.pad(idx, ((0, 0), (0, k * chunk_slots - slots)),
                         constant_values=-1)
-        idx_s = jnp.moveaxis(idx_s.reshape(bsz, k, max_slots), 1, 0)
+        idx_s = jnp.moveaxis(idx_s.reshape(bsz, k, chunk_slots), 1, 0)
         return jax.lax.map(
             lambda i: batch_cluster_eval_pallas(
                 i, par, tgt, src_pts, src_q, kernel, **opts),
             idx_s).sum(axis=0)
-    rows = max(8, LIST_SMEM_BYTES // (4 * slots) // 8 * 8)
-    if bsz <= rows:
+    if nchunk == 1:
         return _batch_cluster_call(idx, par, tgt, src_pts, src_q, kernel,
                                    **opts)
-    nchunk = -(-bsz // rows)
     pad = nchunk * rows - bsz
     idx_c = jnp.pad(idx, ((0, pad), (0, 0)), constant_values=-1)
     tgt_c = jnp.pad(tgt, ((0, pad), (0, 0), (0, 0)))
@@ -199,6 +237,7 @@ def _batch_cluster_call(
     kahan: bool = False,
     r2_mode: str = "diff",
     interpret: bool = False,
+    name: str = "batch_cluster",
 ) -> jnp.ndarray:
     """One `pallas_call` over grid (batch, target-tile, list-slot)."""
     bsz, _, nb = tgt.shape
@@ -259,6 +298,7 @@ def _batch_cluster_call(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bsz, 1, nb), tgt.dtype),
         interpret=interpret,
+        name=name,
         **kwargs,
     )(idx, par.astype(tgt.dtype), tgt, src_pts, src_q[:, None, :])
     return phi[:, 0, :]

@@ -108,6 +108,7 @@ def modified_charges_pallas(
                                lambda ci, ti: (ci, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((c, n1, n1, n1), pts.dtype),
         interpret=interpret,
+        name="modified_charges",
         **kwargs,
     )(pts, q[:, None, :], nodes[..., None], w[:, None])
     return qhat.reshape(c, n1 * n1 * n1)

@@ -167,7 +167,7 @@ _FLAT_MAX = 1 << 18
 @functools.partial(
     jax.jit,
     static_argnames=("kernel", "space", "backend", "target_tile",
-                     "batch_chunk", "kahan", "r2_mode"))
+                     "batch_chunk", "kahan", "r2_mode", "name"))
 def batch_cluster_eval(
     idx: jnp.ndarray,      # (B, S) int, -1 = empty slot
     tgt: jnp.ndarray,      # (B, NB, 3)
@@ -182,8 +182,12 @@ def batch_cluster_eval(
     batch_chunk: int = 16,
     kahan: bool = False,
     r2_mode: str = "diff",
+    name: str = "batch_cluster",
 ) -> jnp.ndarray:
-    """phi (B, NB) = sum over list slots of batch-cluster interactions."""
+    """phi (B, NB) = sum over list slots of batch-cluster interactions.
+
+    `name` names the Pallas kernel, so a profiler trace tells the call
+    sites apart."""
     backend = resolve_backend(backend)
     if backend in ("pallas", "pallas_interpret"):
         tgt_cm = jnp.swapaxes(tgt, -1, -2)          # (B, 3, NB)
@@ -195,7 +199,7 @@ def batch_cluster_eval(
             idx, par, tgt_cm, src_cm, src_q, kernel,
             pspec=pspec, space=space,
             target_tile=target_tile, kahan=kahan, r2_mode=r2_mode,
-            interpret=(backend == "pallas_interpret"),
+            interpret=(backend == "pallas_interpret"), name=name,
         )
         return phi[:, :nb]
     if backend != "xla":

@@ -6,9 +6,8 @@ invariants (DESIGN.md §11). Three layers:
 - `resolver`: walks the package, resolves which functions are
   (transitively) traced — ``@jax.jit`` / ``partial(jit, ...)``
   decorators, ``jax.jit(fn)`` / ``shard_map(fn)`` / ``pallas_call(fn)``
-  / ``vmap(fn)`` call forms, obs ``traced()``-decorated helpers — and
-  maintains a call graph so rules apply to everything reachable from a
-  trace entry point.
+  / ``vmap(fn)`` call forms — and maintains a call graph so rules apply
+  to everything reachable from a trace entry point.
 - `rules`: a registry of small rule classes (id, severity, fixture
   tests) covering host-sync-in-jit, unhashable static args, the devtree
   scatter/sort-free contracts, obs-gated ``block_until_ready``, donation

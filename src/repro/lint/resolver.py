@@ -8,9 +8,7 @@ Entry points recognized (the forms this repo actually uses):
 
 - decorator forms: ``@jax.jit``, ``@jit``,
   ``@functools.partial(jax.jit, static_argnames=...)``,
-  ``@partial(jit, ...)``, and the obs span decorator ``@traced`` /
-  ``@_trace.traced(...)`` (span-wrapped device helpers are held to the
-  same trace-safety rules: they run inside jit regions by convention);
+  and ``@partial(jit, ...)``;
 - call forms: ``jax.jit(fn, ...)``, ``vmap(fn)``, ``shard_map(fn,
   mesh=...)``,
   ``pl.pallas_call(kernel, ...)`` — ``fn`` resolved lexically (local
@@ -52,8 +50,6 @@ VMAP_NAMES = {"jax.vmap", "vmap"}
 SHARD_MAP_SUFFIX = "shard_map"
 PALLAS_CALL_SUFFIX = "pallas_call"
 PARTIAL_NAMES = {"functools.partial", "partial"}
-TRACED_DECORATOR_SUFFIX = "traced"  # repro.obs.trace.traced
-
 # Attribute-call resolution guards (see module docstring).
 ATTR_CANDIDATE_CAP = 4
 COMMON_METHOD_NAMES = {
@@ -379,17 +375,8 @@ class TraceResolver:
         # @jax.jit / @jit
         if _is_jit_callable(dec):
             return f"@{dotted_name(dec)}"
-        d = dotted_name(dec)
-        # @traced / @_trace.traced (obs span decorator convention)
-        if d is not None and (d == TRACED_DECORATOR_SUFFIX
-                              or d.endswith("." + TRACED_DECORATOR_SUFFIX)):
-            return f"@{d}"
         if isinstance(dec, ast.Call):
             dc = dotted_name(dec.func)
-            if dc is not None and (dc == TRACED_DECORATOR_SUFFIX or
-                                   dc.endswith("." +
-                                               TRACED_DECORATOR_SUFFIX)):
-                return f"@{dc}(...)"
             if _is_jit_callable(dec.func):
                 return f"@{dc}(...)"
             if dc in PARTIAL_NAMES and dec.args \

@@ -2,8 +2,9 @@
 
 Four small pieces, one measurement substrate (DESIGN.md §9):
 
-- :mod:`repro.obs.trace` — nested phase-span tracer with Chrome-trace
-  export; allocation-free no-ops while disabled.
+- :mod:`repro.obs.trace` — nested phase-span tracer on the host clock
+  and the profiler's, with Chrome-trace export; allocation-free no-ops
+  while disabled.
 - :mod:`repro.obs.events` — global compile/retrace event log; every jit
   compile records its static key, call site, and wall time.
 - :mod:`repro.obs.occupancy` — device-side occupancy counters (fused
@@ -20,7 +21,7 @@ Typical use::
     print(obs.phase_totals())
 """
 from repro.obs.trace import (  # noqa: F401
-    span, traced, enable, disable, enabled, clear,
+    span, enable, disable, enabled, clear,
     spans, phase_totals, chrome_trace, write_chrome_trace,
 )
 from repro.obs.events import (  # noqa: F401
@@ -35,7 +36,7 @@ from repro.obs.report import (  # noqa: F401
 )
 
 __all__ = [
-    "span", "traced", "enable", "disable", "enabled", "clear",
+    "span", "enable", "disable", "enabled", "clear",
     "spans", "phase_totals", "chrome_trace", "write_chrome_trace",
     "EventLog", "log", "log_compiles", "record", "cache_size",
     "occupancy_counters", "static_occupancy",

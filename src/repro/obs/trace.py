@@ -1,4 +1,4 @@
-"""Phase-span tracer: nested monotonic-clock spans with Chrome-trace export.
+"""Phase-span tracer: nested spans on the host clock and the profiler's.
 
 The tracer is a process-global, thread-aware span recorder. Design
 constraints (DESIGN.md §9):
@@ -6,18 +6,18 @@ constraints (DESIGN.md §9):
 - **Allocation-free when disabled.** ``span(name)`` returns a singleton
   null context manager when tracing is off — no object is allocated, no
   clock is read. Hot loops (the MD step) may therefore leave their span
-  calls in place permanently. Callers that want zero overhead must not
-  pass kwargs at the call site (building the kwargs dict allocates
-  before the disabled check can run); the instrumented hot paths in this
-  repo pass the name only.
+  calls in place permanently.
 - **Nesting by thread-local stack.** Spans carry a depth and a parent
   name so the Chrome-trace export reconstructs the tree; reentrancy
   (same span name nested inside itself) is allowed and preserved.
-- **Honest device attribution.** jax dispatch is async: a span around a
-  jitted call measures enqueue time only. Instrumented device phases
-  call ``jax.block_until_ready`` *inside* their span **only when tracing
-  is enabled**, so enabled traces attribute device time to the phase
-  that launched it while disabled runs keep the async pipeline.
+- **One clock with the device.** An enabled span also opens a
+  ``jax.profiler.TraceAnnotation`` of its name, inside its own
+  ``perf_counter`` record. While a profiler trace runs, the span lands
+  on the profiler's host plane, on the clock the device planes are
+  aligned to, so a trace reader can name device time and idle gaps by
+  the program's phases. jax dispatch is async, so a span around a jitted
+  call holds its dispatch, not its device time: device time is read from
+  the device plane, and spans need not sync to be honest.
 
 Spans are recorded into a bounded global buffer (oldest dropped past
 ``MAX_SPANS``) and exported either as ``phase_totals()`` (flat
@@ -27,15 +27,16 @@ loads in ``chrome://tracing`` and Perfetto.
 """
 from __future__ import annotations
 
-import functools
 import json
 import os
 import threading
 import time
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Dict, List
+
+from jax.profiler import TraceAnnotation
 
 __all__ = [
-    "span", "traced", "enable", "disable", "enabled", "clear",
+    "span", "enable", "disable", "enabled", "clear",
     "spans", "phase_totals", "chrome_trace", "write_chrome_trace",
     "MAX_SPANS",
 ]
@@ -61,27 +62,16 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
-    def tag(self, **kwargs):  # parity with _Span; drops everything
-        return self
-
 
 _NULL = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("name", "cat", "args", "_t0", "_depth", "_parent")
+    __slots__ = ("name", "cat", "_t0", "_depth", "_parent", "_note")
 
-    def __init__(self, name: str, cat: str, args: Optional[dict]):
+    def __init__(self, name: str, cat: str):
         self.name = name
         self.cat = cat
-        self.args = args
-
-    def tag(self, **kwargs):
-        """Attach tags to an open span (cheap: only runs when enabled)."""
-        if self.args is None:
-            self.args = {}
-        self.args.update(kwargs)
-        return self
 
     def __enter__(self):
         stack = getattr(_tls, "stack", None)
@@ -91,9 +81,14 @@ class _Span:
         self._parent = stack[-1].name if stack else None
         stack.append(self)
         self._t0 = time.perf_counter()
+        # Opened inside the perf_counter record, so the profiler's span
+        # lies within it.
+        self._note = TraceAnnotation(self.name)
+        self._note.__enter__()
         return self
 
     def __exit__(self, *exc):
+        self._note.__exit__(*exc)
         t1 = time.perf_counter()
         _tls.stack.pop()
         rec = {
@@ -105,8 +100,6 @@ class _Span:
             "parent": self._parent,
             "tid": threading.get_ident(),
         }
-        if self.args:
-            rec["args"] = self.args
         global _dropped
         with _lock:
             if len(_spans) >= MAX_SPANS:
@@ -116,45 +109,17 @@ class _Span:
         return False
 
 
-def span(name: str, cat: str = "phase", **args):
+def span(name: str, cat: str = "phase"):
     """Open a phase span. Returns a no-op singleton when tracing is off.
 
     Usage::
 
         with obs.span("md.finish"):
             arrays = finish(...)
-
-    For zero-overhead-when-disabled call sites, pass only ``name`` (and
-    optionally ``cat``); kwargs are evaluated by the caller before the
-    enabled check and therefore allocate.
     """
     if not _enabled:
         return _NULL
-    return _Span(name, cat, args or None)
-
-
-def traced(name: Optional[str] = None, cat: str = "phase") -> Callable:
-    """Decorator form: wrap a function body in a span.
-
-    ``@traced`` or ``@traced("custom.name")``. The enabled check runs
-    per call, so decorating a function keeps it allocation-free while
-    tracing is off.
-    """
-    def deco(fn: Callable) -> Callable:
-        label = name or f"{fn.__module__.rsplit('.', 1)[-1]}.{fn.__qualname__}"
-
-        @functools.wraps(fn)
-        def wrapper(*a, **kw):
-            if not _enabled:
-                return fn(*a, **kw)
-            with _Span(label, cat, None):
-                return fn(*a, **kw)
-        return wrapper
-
-    if callable(name):  # bare @traced
-        fn, name = name, None
-        return deco(fn)
-    return deco
+    return _Span(name, cat)
 
 
 def enable() -> None:
@@ -230,8 +195,6 @@ def chrome_trace(process_name: str = "repro") -> Dict[str, Any]:
             "pid": os.getpid(),
             "tid": r["tid"],
         }
-        if "args" in r:
-            ev["args"] = r["args"]
         events.append(ev)
     meta = {"displayTimeUnit": "ms", "traceEvents": events}
     if _dropped:

@@ -234,3 +234,58 @@ def test_kahan_matmul_compose(rng, x64):
         idx, tgt, src, q, kernel=kern, backend="pallas_interpret",
         target_tile=8, kahan=True, r2_mode="matmul"))
     assert np.abs(got - want).max() / np.abs(want).max() < 1e-5
+
+
+def _hand_launched(idx, lanes, width, max_slots, rows_of):
+    """Grid cells the Pallas path launches, enumerated call by call the
+    way `batch_cluster_eval_pallas` splits a list."""
+    bsz, slots = idx.shape
+    if slots > max_slots:
+        k = -(-slots // max_slots)
+        chunks = [max_slots] * k
+    else:
+        chunks = [slots]
+    total = 0
+    for cs in chunks:
+        rows = rows_of(cs)
+        calls = [bsz] if bsz <= rows else [rows] * (-(-bsz // rows))
+        total += sum(r * lanes * cs * width for r in calls)
+    return total
+
+
+def test_kernel_work_counts_a_split_plan_by_hand(monkeypatch):
+    """`plan.stats()["kernel_work"]` against a count made slot by slot
+    and call by call, on a plan whose lists split on both axes."""
+    from repro.core.api import TreecodeConfig, TreecodeSolver
+    from repro.kernels import batch_cluster
+
+    budget = 8 * 4 * 4
+    monkeypatch.setattr(batch_cluster, "LIST_SMEM_BYTES", budget)
+    x = np.random.default_rng(3).uniform(-1, 1, (3000, 3))
+    degree = 2
+    plan = TreecodeSolver(TreecodeConfig(
+        theta=0.7, degree=degree, leaf_size=64, batch_size=64,
+        backend="xla")).plan(x, nranks=1)
+    work = plan.stats()["kernel_work"]
+
+    a = {k: np.asarray(v) for k, v in plan.arrays.items()
+         if not isinstance(v, tuple)}
+    k3 = (degree + 1) ** 3
+    tgt = a["tgt_mask"].sum(1)
+    leaf_n = (a["leaf_gather"] >= 0).sum(1)
+    lanes = 256 * -(-a["tgt_mask"].shape[1] // 256)
+    max_slots = budget // 32
+
+    def rows_of(slots):
+        return max(8, budget // (4 * slots) // 8 * 8)
+
+    for name, idx, width, src in (
+            ("approx", a["approx_idx"], k3, lambda c: k3),
+            ("direct", a["direct_idx"], a["leaf_gather"].shape[1],
+             lambda c: leaf_n[c])):
+        assert idx.shape[1] > max_slots and idx.shape[0] > rows_of(max_slots)
+        useful = sum(int(tgt[b]) * int(src(c))
+                     for b in range(idx.shape[0]) for c in idx[b] if c >= 0)
+        assert work[name] == dict(
+            launched=_hand_launched(idx, lanes, width, max_slots, rows_of),
+            useful=useful)

@@ -79,18 +79,64 @@ def test_phase_totals_prefix_and_sibling_sum(tracer):
     assert totals["md.advance"] == pytest.approx(sum(both) * 1e3)
 
 
-def test_traced_decorator_and_tags(tracer):
-    @obs.traced("custom.fn")
-    def f():
-        return 7
+def _host_plane(log_dir):
+    """(host-plane events as (name, start, end) in realtime seconds) of
+    the newest profile under `log_dir`."""
+    import glob
+    import os
 
-    assert f() == 7
-    with obs.span("tagged").tag(n=3):
-        pass
-    recs = obs.spans()
-    assert any(r["name"] == "custom.fn" for r in recs)
-    tagged = [r for r in recs if r["name"] == "tagged"]
-    assert tagged[0]["args"] == {"n": 3}
+    from jax.profiler import ProfileData
+
+    path = sorted(glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
+                            recursive=True), key=os.path.getmtime)[-1]
+    data = ProfileData.from_file(path)
+    # Event times count from the profile's start, a realtime stamp.
+    t0 = [int(v) for p in data.planes for k, v in p.stats
+          if k == "profile_start_time"][0] * 1e-9
+    return [(e.name, t0 + e.start_ns * 1e-9,
+             t0 + (e.start_ns + e.duration_ns) * 1e-9)
+            for p in data.planes if p.name.startswith("/host:")
+            for line in p.lines for e in line.events]
+
+
+def test_enabled_span_lands_on_the_profiler_host_plane(tmp_path):
+    import time
+
+    import jax
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    obs.clear()
+    obs.enable()
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        ref = time.perf_counter(), time.time_ns()
+        with obs.span("plan.probe"):
+            time.sleep(0.02)
+        obs.disable()
+        with obs.span("plan.hidden"):
+            time.sleep(0.005)
+    finally:
+        jax.profiler.stop_trace()
+        obs.disable()
+    host = _host_plane(str(tmp_path))
+    names = [e[0] for e in host]
+    assert names.count("plan.probe") == 1
+    assert "plan.hidden" not in names
+    assert [r["name"] for r in obs.spans()] == ["plan.probe"]
+    rec = obs.spans()[0]
+    obs.clear()
+
+    # The profiler's span lies within the perf_counter record, with the
+    # two clocks tied at `ref` (slack for reading them one after another).
+    def realtime(t_perf):
+        return ref[1] * 1e-9 + (t_perf - ref[0])
+
+    _, start, end = host[names.index("plan.probe")]
+    slack = 2e-4
+    assert start >= realtime(rec["t0"]) - slack
+    assert end <= realtime(rec["t0"] + rec["dur"]) + slack
+    assert 0.02 <= end - start <= rec["dur"]
 
 
 def test_chrome_trace_round_trips_json(tracer, tmp_path):
