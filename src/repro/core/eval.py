@@ -1028,14 +1028,15 @@ def _pad_plan_impl(plan: Plan, caps: Capacities) -> Plan:
 
 
 def kernel_work(plan: Plan, degree: int) -> dict:
-    """Pair evaluations the two `batch_cluster` lists of `plan` launch
-    and need, ``{"approx"|"direct": {"launched", "useful"}}``
-    (`kernels.batch_cluster.kernel_work`). Host arithmetic: fetches the
-    lists and masks once, off the hot path.
+    """Pair evaluations the two `batch_cluster` lists of `plan` launch,
+    skip and need, ``{"approx"|"direct": {"launched", "skipped",
+    "useful"}}`` (`kernels.batch_cluster.kernel_work`). Host arithmetic:
+    fetches the lists and masks once, off the hot path.
 
     With a Verlet skin the direct list runs with its skin-direct columns
-    appended (`_skin_routed_lists`); they are launched, and at the
-    build's own geometry the runtime gate leaves every one of them empty.
+    appended (`_skin_routed_lists`); at the build's own geometry the
+    runtime gate leaves every one of them empty, so they count as
+    skipped.
     """
     keys = ("approx_idx", "direct_idx", "tgt_mask", "leaf_gather")
     a = jax.device_get({k: plan.arrays[k] for k in keys})

@@ -31,17 +31,23 @@ difference form.
 Layout: coordinates are coordinate-major (..., 3, P) so the particle axis
 is the TPU lane dimension.
 
-Sentinel contract: a ``-1`` slot in the interaction-list index array
-contributes exactly zero, and sentinels may appear at ANY position in a
-row, not only as trailing padding. The accumulation masks every slot
-individually (``valid * pot`` / the Kahan variant below) and the output
-tile is initialized at slot 0 regardless of that slot's validity, so
-interior sentinels are safe — the Verlet-skin runtime gate
-(drift-budget v2, DESIGN.md §4) relies on this to switch dual-listed
-pairs between the approx and direct kernels by current distance without
-re-packing the lists. Host-BUILT lists still emit trailing padding only
-(less wasted gather bandwidth); the gate is the one producer of
-interior sentinels.
+Sentinel contract: any negative slot in the interaction-list index
+array contributes exactly zero and costs neither compute nor a copy, and
+sentinels may appear at ANY position in a row, not only as trailing
+padding. Each call reads its slot's index from SMEM and runs the tile
+under ``pl.when(idx >= 0)``; the output tile (and the Kahan compensation)
+is initialized at slot 0 regardless of that slot's validity, so a row of
+sentinels writes zeros. Before the call every negative slot is rewritten
+to ``-(1 + c)``, with ``c`` the row's last valid cluster before it (its
+first valid one for leading sentinels, 0 for an empty row), and the
+source and charge index maps decode it back to ``c``: the block stays the
+one already resident, and the grid pipeline does not copy a block whose
+index did not change. Interior sentinels are safe — the Verlet-skin
+runtime gate (drift-budget v2, DESIGN.md §4) relies on this to switch
+dual-listed pairs between the approx and direct kernels by current
+distance without re-packing the lists. Host-built lists emit ``-1`` as
+trailing padding only; the gate is the one producer of interior
+sentinels.
 """
 from __future__ import annotations
 
@@ -93,21 +99,21 @@ def _read_params(par_ref, pspec):
     return unpack_params(lambda i: par_ref[0, i], pspec)
 
 
-def _slot_potential(idx_ref, par_ref, tgt_ref, src_ref, q_ref, *,
-                    kernel: Kernel, r2_mode: str, space, pspec, dtype):
-    """(1, NT) contribution of the current list slot, zero for a -1
-    sentinel. The charge contraction runs as a (1, m) x (NT, m)^T matmul
-    on the MXU, so every value stays 2-D (lane axis = particles)."""
-    b = pl.program_id(0)
-    s = pl.program_id(2)
+def _slot_potential(par_ref, tgt_ref, src_ref, q_ref, *, kernel: Kernel,
+                    r2_mode: str, space, pspec, dtype):
+    """(1, NT) contribution of the current list slot. The charge
+    contraction runs as a (1, m) x (NT, m)^T matmul on the MXU, so every
+    value stays 2-D (lane axis = particles)."""
     r2 = _pair_r2(tgt_ref[0], src_ref[0], r2_mode, space)    # (NT, m)
     g = kernel(r2, _read_params(par_ref, pspec))             # 0 at r2 == 0
-    pot = jax.lax.dot_general(
+    return jax.lax.dot_general(
         q_ref[0], g, dimension_numbers=(((1,), (1,)), ((), ())),
         precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=dtype)                        # (1, NT)
-    valid = (idx_ref[b, s] >= 0).astype(dtype)
-    return valid * pot
+
+
+def _valid_slot(idx_ref):
+    return idx_ref[pl.program_id(0), pl.program_id(2)] >= 0
 
 
 def _body(idx_ref, par_ref, tgt_ref, src_ref, q_ref, out_ref, **opts):
@@ -115,8 +121,10 @@ def _body(idx_ref, par_ref, tgt_ref, src_ref, q_ref, out_ref, **opts):
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    out_ref[0] += _slot_potential(idx_ref, par_ref, tgt_ref, src_ref, q_ref,
-                                  dtype=out_ref.dtype, **opts)
+    @pl.when(_valid_slot(idx_ref))
+    def _accumulate():
+        out_ref[0] += _slot_potential(par_ref, tgt_ref, src_ref, q_ref,
+                                      dtype=out_ref.dtype, **opts)
 
 
 def _body_kahan(idx_ref, par_ref, tgt_ref, src_ref, q_ref, out_ref,
@@ -129,12 +137,37 @@ def _body_kahan(idx_ref, par_ref, tgt_ref, src_ref, q_ref, out_ref,
         out_ref[...] = jnp.zeros_like(out_ref)
         comp_ref[...] = jnp.zeros_like(comp_ref)
 
-    y = _slot_potential(idx_ref, par_ref, tgt_ref, src_ref, q_ref,
-                        dtype=out_ref.dtype, **opts) - comp_ref[...]
-    acc = out_ref[0]
-    tsum = acc + y
-    comp_ref[...] = (tsum - acc) - y
-    out_ref[0] = tsum
+    @pl.when(_valid_slot(idx_ref))
+    def _accumulate():
+        y = _slot_potential(par_ref, tgt_ref, src_ref, q_ref,
+                            dtype=out_ref.dtype, **opts) - comp_ref[...]
+        acc = out_ref[0]
+        tsum = acc + y
+        comp_ref[...] = (tsum - acc) - y
+        out_ref[0] = tsum
+
+
+def resident_sentinels(idx: jnp.ndarray) -> jnp.ndarray:
+    """`idx` (B, S) with every negative slot rewritten to ``-(1 + c)``:
+    `c` is the row's last valid cluster before the slot, else its first
+    valid one, else 0. `cluster_block` maps it back to `c`, so a sentinel
+    slot asks for the block a neighbouring valid slot already holds."""
+    slots = idx.shape[1]
+    valid = idx >= 0
+    pos = jnp.where(valid, jnp.arange(slots, dtype=idx.dtype), -1)
+    last = jax.lax.cummax(pos, axis=1)
+    first = jnp.argmax(valid, axis=1).astype(idx.dtype)[:, None]
+    fill = jnp.take_along_axis(idx, jnp.where(last >= 0, last, first),
+                               axis=1)
+    return jnp.where(valid, idx, -1 - jnp.maximum(fill, 0))
+
+
+def cluster_block(b, t, s, idx_ref, par_ref):
+    """Index map of the source points and charges at grid step (b, t, s):
+    the slot's cluster, or for a negative slot the one it encodes."""
+    del t, par_ref
+    v = idx_ref[b, s]
+    return (jnp.where(v >= 0, v, -1 - v), 0, 0)
 
 
 #: SMEM bytes one call's scalar-prefetched interaction list may take. The
@@ -162,29 +195,33 @@ def list_split(bsz: int, slots: int):
 
 def kernel_work(idx, tgt_counts, src_counts, *, target_width: int,
                 source_width: int, target_tile: int = 256) -> dict:
-    """Pair evaluations of one `batch_cluster` list, launched and useful.
+    """Pair evaluations of one `batch_cluster` list: launched, skipped
+    and useful.
 
-    `idx` (B, S) is the list as the kernel runs it (-1 = empty slot),
-    `tgt_counts` (B,) the real targets of each batch row, `src_counts`
-    (C,) the real sources of each cluster id. `launched`
-    counts what the Pallas grids execute: rows x target lanes (padded
-    to `target_tile`) x slots x `source_width`, over every chunk of
-    `list_split`; sentinel slots and padding included. `useful` counts
+    `idx` (B, S) is the list as the kernel runs it (negative = empty
+    slot), `tgt_counts` (B,) the real targets of each batch row,
+    `src_counts` (C,) the real sources of each cluster id. Every grid
+    step covers target lanes (padded to `target_tile`) x `source_width`
+    pairs. `launched` counts the steps the Pallas grids compute, one per
+    non-sentinel slot and target tile; `skipped` counts the sentinel
+    steps, which compute and copy nothing, over every chunk of
+    `list_split` (its slot and row padding included). `useful` counts
     real targets x real sources over the non-sentinel slots."""
     idx = np.asarray(idx)
     bsz, slots = idx.shape
     slot_chunks, chunk_slots, row_chunks, rows = list_split(bsz, slots)
     lanes = -(-target_width // target_tile) * target_tile
-    launched = (slot_chunks * chunk_slots * row_chunks * rows * lanes
-                * source_width)
+    grid = slot_chunks * chunk_slots * row_chunks * rows * lanes * source_width
+    launched = int((idx >= 0).sum()) * lanes * source_width
     src = np.asarray(src_counts, np.int64)
     per_row = np.where(idx >= 0, src[np.maximum(idx, 0)], 0).sum(1)
     useful = int((np.asarray(tgt_counts, np.int64) * per_row).sum())
-    return dict(launched=int(launched), useful=useful)
+    return dict(launched=launched, skipped=int(grid - launched),
+                useful=useful)
 
 
 def batch_cluster_eval_pallas(
-    idx: jnp.ndarray,      # (B, S) int32 cluster ids, -1 = empty
+    idx: jnp.ndarray,      # (B, S) int32 cluster ids, negative = empty
     par: jnp.ndarray,      # (1, P) packed kernel parameter values
     tgt: jnp.ndarray,      # (B, 3, NB) coordinate-major padded targets
     src_pts: jnp.ndarray,  # (C, 3, m) coordinate-major cluster points
@@ -254,14 +291,6 @@ def _batch_cluster_call(
         del s, idx_ref, par_ref
         return (b, 0, t)
 
-    def src_map(b, t, s, idx_ref, par_ref):
-        del t, par_ref
-        return (jnp.maximum(idx_ref[b, s], 0), 0, 0)
-
-    def q_map(b, t, s, idx_ref, par_ref):
-        del t, par_ref
-        return (jnp.maximum(idx_ref[b, s], 0), 0, 0)
-
     def out_map(b, t, s, idx_ref, par_ref):
         del s, idx_ref, par_ref
         return (b, 0, t)
@@ -284,8 +313,8 @@ def _batch_cluster_call(
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 3, nt), tgt_map),
-            pl.BlockSpec((1, 3, m), src_map),
-            pl.BlockSpec((1, 1, m), q_map),
+            pl.BlockSpec((1, 3, m), cluster_block),
+            pl.BlockSpec((1, 1, m), cluster_block),
         ],
         out_specs=pl.BlockSpec((1, 1, nt), out_map),
         scratch_shapes=scratch,
@@ -300,5 +329,6 @@ def _batch_cluster_call(
         interpret=interpret,
         name=name,
         **kwargs,
-    )(idx, par.astype(tgt.dtype), tgt, src_pts, src_q[:, None, :])
+    )(resident_sentinels(idx), par.astype(tgt.dtype), tgt, src_pts,
+      src_q[:, None, :])
     return phi[:, 0, :]

@@ -88,6 +88,75 @@ def test_batch_cluster_all_empty_slots(rng):
         np.testing.assert_array_equal(np.asarray(got), 0.0)
 
 
+def _compacted(idx):
+    """Each row's valid slots moved to the front, in order, -1 after."""
+    out = np.full_like(idx, -1)
+    for b, row in enumerate(idx):
+        keep = row[row >= 0]
+        out[b, :keep.size] = keep
+    return out
+
+
+@pytest.mark.parametrize("pattern", [
+    "leading", "interior", "trailing", "empty_row", "row_chunks"])
+@pytest.mark.parametrize("kahan", [False, True])
+def test_batch_cluster_sentinel_slots_skip(rng, monkeypatch, pattern,
+                                           kahan):
+    """A sentinel slot adds nothing and asks for the block a valid slot
+    of its row already holds: the plain sum is the compacted list's to
+    the bit, the Kahan sum close to it, an all-sentinel row is zero."""
+    from repro.kernels import batch_cluster
+
+    B, S, C = 5, 7, 6
+    if pattern == "row_chunks":
+        # 4 slots a call and 8 rows a call: 19 rows pad to 24, 7 slots to
+        # 8, so whole padding rows and padding slots run.
+        monkeypatch.setattr(batch_cluster, "LIST_SMEM_BYTES", 8 * 4 * 4)
+        B = 19
+    idx, tgt, src, q = _case(rng, B, S, 16, C, 24, np.float32)
+    ids = rng.integers(0, C, (B, S))
+    valid = {
+        "leading": np.arange(S) >= 3,
+        "interior": np.isin(np.arange(S), [1, 2, 4]),
+        "trailing": np.arange(S) < 4,
+        "empty_row": np.ones(S, bool),
+        "row_chunks": np.isin(np.arange(S), [0, 2, 3, 5]),
+    }[pattern]
+    lst = np.where(valid[None, :], ids, -1).astype(np.int32)
+    if pattern in ("empty_row", "row_chunks"):
+        lst[1] = -1
+
+    res = np.asarray(batch_cluster.resident_sentinels(jnp.asarray(lst)))
+    assert (res[lst >= 0] == lst[lst >= 0]).all()
+    assert (res[lst < 0] < 0).all()
+    blocks = np.array([[int(batch_cluster.cluster_block(b, 0, s, res, None)[0])
+                        for s in range(S)] for b in range(B)])
+    for row, blk in zip(lst, blocks):
+        # the row's blocks change only where a valid id changes them
+        seq = row[row >= 0] if (row >= 0).any() else np.zeros(1, int)
+        assert blk[0] == seq[0]
+        changes = blk[1:][blk[1:] != blk[:-1]]
+        np.testing.assert_array_equal(
+            changes, seq[1:][seq[1:] != seq[:-1]])
+
+    kern = coulomb()
+
+    def run(lst):
+        return np.asarray(ops.batch_cluster_eval(
+            jnp.asarray(lst), tgt, src, q, kernel=kern,
+            backend="pallas_interpret", target_tile=8, kahan=kahan))
+
+    got, want = run(lst), run(_compacted(lst))
+    if kahan:
+        np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+    else:
+        np.testing.assert_array_equal(got, want)
+    np.testing.assert_allclose(
+        got, np.asarray(ref.ref_batch_cluster_eval(
+            jnp.asarray(lst), tgt, src, q, kern)), rtol=2e-4, atol=2e-4)
+    np.testing.assert_array_equal(got[~(lst >= 0).any(1)], 0.0)
+
+
 def test_self_interaction_masked(rng):
     # A target coincident with a source must not produce inf/nan.
     tgt = jnp.asarray([[[0.0, 0.0, 0.0], [1.0, 0.0, 0.0]]])
@@ -253,9 +322,32 @@ def _hand_launched(idx, lanes, width, max_slots, rows_of):
     return total
 
 
+def _hand_computed(idx, lanes, width, max_slots, rows_of):
+    """(computed, skipped) grid cells: the list cut into the calls
+    `batch_cluster_eval_pallas` makes, sentinel-padded on both axes, and
+    each call's slots counted one by one."""
+    bsz, slots = idx.shape
+    cs = max_slots if slots > max_slots else slots
+    k = -(-slots // cs)
+    rows = rows_of(cs)
+    nrows = bsz if bsz <= rows else rows * -(-bsz // rows)
+    full = np.full((nrows, k * cs), -1)
+    full[:bsz, :slots] = idx
+    computed = skipped = 0
+    for c in range(k):
+        for r0 in range(0, nrows, min(rows, nrows)):
+            for slot in full[r0:r0 + rows, c * cs:(c + 1) * cs].ravel():
+                if slot >= 0:
+                    computed += lanes * width
+                else:
+                    skipped += lanes * width
+    return computed, skipped
+
+
 def test_kernel_work_counts_a_split_plan_by_hand(monkeypatch):
     """`plan.stats()["kernel_work"]` against a count made slot by slot
-    and call by call, on a plan whose lists split on both axes."""
+    and call by call, on a plan whose lists split on both axes: the
+    computed and skipped grid cells together are every cell launched."""
     from repro.core.api import TreecodeConfig, TreecodeSolver
     from repro.kernels import batch_cluster
 
@@ -286,6 +378,10 @@ def test_kernel_work_counts_a_split_plan_by_hand(monkeypatch):
         assert idx.shape[1] > max_slots and idx.shape[0] > rows_of(max_slots)
         useful = sum(int(tgt[b]) * int(src(c))
                      for b in range(idx.shape[0]) for c in idx[b] if c >= 0)
-        assert work[name] == dict(
-            launched=_hand_launched(idx, lanes, width, max_slots, rows_of),
-            useful=useful)
+        computed, skipped = _hand_computed(idx, lanes, width, max_slots,
+                                           rows_of)
+        assert skipped > 0
+        assert computed + skipped == _hand_launched(idx, lanes, width,
+                                                    max_slots, rows_of)
+        assert work[name] == dict(launched=computed, skipped=skipped,
+                                  useful=useful)

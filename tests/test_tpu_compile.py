@@ -61,10 +61,14 @@ def _params(sharding, kernel):
 
 
 def _compile_batch_cluster(one_chip, kernel, *, B=4, S=8, NB=4000, C=64,
-                           m=4000, vmap=0, **opts):
+                           m=4000, vmap=0, lst=None, **opts):
+    """`lst`, if given, is a fixed (B, S) list compiled in as a constant
+    in place of the list argument."""
     kw = dict(kernel=kernel.stripped(), backend="pallas", **opts)
 
     def f(idx, tgt, src, q, params):
+        if lst is not None:
+            idx = jnp.asarray(lst)
         return ops.batch_cluster_eval(idx, tgt, src, q, params, **kw)
 
     lead = (vmap,) if vmap else ()
@@ -79,7 +83,7 @@ def _compile_batch_cluster(one_chip, kernel, *, B=4, S=8, NB=4000, C=64,
 
 @pytest.mark.parametrize("case", [
     "coulomb_m4000", "yukawa_periodic", "matmul_r2", "kahan", "vmap",
-    "long_list"])
+    "long_list", "sentinels"])
 def test_batch_cluster_compiles_for_v5e(one_chip, case):
     if case == "coulomb_m4000":
         compiled = _compile_batch_cluster(one_chip, coulomb())
@@ -89,6 +93,16 @@ def test_batch_cluster_compiles_for_v5e(one_chip, case):
         compiled = _compile_batch_cluster(
             one_chip, yukawa(0.8), B=370, S=1024, NB=512, C=600, m=343,
             space=PeriodicBox((48.0, 48.0, 48.0)))
+    elif case == "sentinels":
+        # The paper cell's direct list (1023 batches x 200 slots of 2000
+        # sources, run as row chunks) with interior sentinels, as the
+        # skin gate leaves them, and trailing padding of varying length.
+        lst = np.random.default_rng(0).integers(0, 1000, (1023, 200))
+        lst[:, 5:9] = -1
+        lst[np.arange(200) >= np.arange(1023)[:, None] % 180 + 20] = -1
+        compiled = _compile_batch_cluster(
+            one_chip, coulomb(), B=1023, S=200, NB=2048, C=1000, m=2000,
+            lst=lst.astype(np.int32))
     elif case == "yukawa_periodic":
         compiled = _compile_batch_cluster(
             one_chip, yukawa(0.8), NB=512, m=512,
