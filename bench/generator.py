@@ -10,15 +10,24 @@ A traffic file names its `kind` and the parameters of that kind:
     `charge_vectors`, drawn from the seed at set-up) and waits for them.
     A closed loop, one call at a time.
   - ``solve``: one-shot sums over new points. Set-up draws `point_sets`
-    particle sets and their charges from the seed and builds a plan;
-    each solve of the window replans on the next set's points (the host
-    planner, on the plan's kept budget) and evaluates its potentials. A
-    closed loop, one solve at a time.
+    particle sets, the configuration's draws of seed 0 in every run, and
+    their charges from the seed, and builds a plan; each solve of the
+    window replans on the next set's points (the host planner, on the
+    plan's kept budget) and evaluates its potentials. A closed loop, one
+    solve at a time. The points are the same for every seed because the
+    device's work follows the tree of the draw: two uniform draws of
+    10^6 points differ by up to a tenth in kernel time on a TPU v5e.
 
-Every seed runs the same shapes, so after a cell's first run in a
-checkout every program is in the compile cache: ``eval`` plans the same
-points, and ``solve`` pads every plan into one budget that depends on
-the configuration alone (`shape_budget`).
+Every seed runs the same shapes and the same work, so after a cell's
+first run in a checkout every program is in the compile cache: ``eval``
+plans the same points, and ``solve`` the same point sets, each padded
+into one budget that depends on the configuration alone
+(`shape_budget`).
+
+A configuration names its point generator (`system.generator`, a file
+`bench/reference/systems/<generator>.py`) and its ranks (`layout.ranks`):
+one rank plans on one device, more plan the program's sharded plan over
+that many devices (`program_plan`).
 
 Each kind keeps what the timed path produced, and after the window
 compares a sample of it, drawn from the seed, with the float64 reference
@@ -29,7 +38,9 @@ from __future__ import annotations
 
 import dataclasses
 import gc
+import importlib.util
 import time
+from pathlib import Path
 from typing import Dict, List
 
 import numpy as np
@@ -38,9 +49,11 @@ from bench.reference import direct, generators
 
 # Streams of a seed: which draw each is for.
 _POINTS, _CHARGES, _ROWS = 0, 1, 2
-# The draw that is the fixed geometry of `eval` and sets the shape
-# budget of `solve`.
+# The draw that is the fixed geometry of `eval` and `solve` and sets the
+# shape budget of `solve`.
 _FIXED_SEED = 0
+# The checkout whose `bench/` holds the cells' files.
+ROOT = Path(__file__).resolve().parents[1]
 
 
 @dataclasses.dataclass
@@ -68,12 +81,26 @@ def annotate(name: str):
     return jax.profiler.TraceAnnotation(name)
 
 
-def system_points(config: dict, seed: int, stream: int = _POINTS):
-    """(x, q) of a configuration's system for one seed and stream."""
-    sysc = config["system"]
-    if sysc["generator"] == "uniform_cloud":
-        return generators.uniform_cloud(sysc["n"], seed, stream)
-    raise ValueError(f"unknown generator {sysc['generator']!r}")
+def _points_fn(generator: str, root: Path):
+    path = root / "bench" / "reference" / "systems" / f"{generator}.py"
+    if not path.exists():
+        raise ValueError(f"unknown generator {generator!r}: no {path}")
+    spec = importlib.util.spec_from_file_location(
+        "bench_system_" + generator, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.points
+
+
+def system_points(config: dict, seed: int, stream: int = _POINTS,
+                  root: Path = ROOT):
+    """(x, q) of a configuration's system for one seed and stream: the
+    `points(n, seed, stream, **params)` of the generator file that
+    `system.generator` names, with the group's other keys but `n` as its
+    parameters."""
+    params = dict(config["system"])
+    points = _points_fn(params.pop("generator"), root)
+    return points(int(params.pop("n")), seed, stream, **params)
 
 
 def treecode_config(config: dict):
@@ -90,15 +117,29 @@ def kernel_args(config: dict) -> dict:
                 kappa=float(t.get("kernel_params", {}).get("kappa", 0.0)))
 
 
-def shape_budget(config: dict):
+def program_plan(config: dict, x, capacities=None):
+    """The program's plan of `x` under a configuration: on one device
+    with one rank, else the program's sharded plan over the first
+    `layout.ranks` devices."""
+    import jax
+    from repro.core.api import TreecodeSolver
+
+    solver = TreecodeSolver(treecode_config(config))
+    ranks = int(config["layout"]["ranks"])
+    if ranks == 1:
+        return solver.plan(x, nranks=1, capacities=capacities)
+    mesh = jax.make_mesh((ranks,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,),
+                         devices=jax.devices()[:ranks])
+    return solver.plan(x, mesh=mesh, capacities=capacities)
+
+
+def shape_budget(config: dict, root: Path = ROOT):
     """The plan budget of a configuration: the program's own padded
     budget (`capacities="auto"`, its default headroom) of the plan over
     the draw of seed 0. A seed whose plan needs more grows it, in set-up."""
-    from repro.core.api import TreecodeSolver
-
-    x, _ = system_points(config, _FIXED_SEED)
-    plan = TreecodeSolver(treecode_config(config)).plan(
-        x, nranks=1, capacities="auto")
+    x, _ = system_points(config, _FIXED_SEED, root=root)
+    plan = program_plan(config, x, capacities="auto")
     budget = plan.capacities
     del plan
     gc.collect()
@@ -127,10 +168,12 @@ class Traffic:
 
     unit = "unit"
 
-    def __init__(self, config: dict, params: dict, seed: int):
+    def __init__(self, config: dict, params: dict, seed: int,
+                 root: Path = ROOT):
         self.config = config
         self.params = params            # the traffic file's parameters
         self.seed = int(seed)
+        self.root = root                # where the generator files are
         self.rows_n = int(params.get("check_rows", 1024))
         self.failed = 0
         self.budget_grown = False
@@ -142,12 +185,10 @@ class Traffic:
 
     def plan(self, x, budget=None):
         """The program's plan of `x`, padded into `budget` if one is given."""
-        from repro.core.api import TreecodeSolver
-
         with annotate("bench.plan"):
-            plan = TreecodeSolver(treecode_config(self.config)).plan(
-                x, nranks=1, capacities=budget)
-        self.budget_grown |= plan.capacities != budget
+            plan = program_plan(self.config, x, capacities=budget)
+        if budget is not None:
+            self.budget_grown |= plan.capacities != budget
         return plan
 
     # setup / run_unit / collect / check are each kind's protocol.
@@ -201,7 +242,7 @@ class EvalTraffic(Traffic):
     def setup(self):
         import jax
 
-        self.x, _ = system_points(self.config, _FIXED_SEED)
+        self.x, _ = system_points(self.config, _FIXED_SEED, root=self.root)
         self.plan_ = self.plan(self.x)
         self.charges = device_charges(self.seed, len(self.x),
                                       int(self.params["charge_vectors"]))
@@ -242,6 +283,11 @@ class EvalTraffic(Traffic):
         """The algorithm's work for one call (bench/reference/worktree)."""
         from bench.reference import worktree
 
+        ranks = int(self.config["layout"]["ranks"])
+        if ranks != 1:
+            raise NotImplementedError(
+                "EvalTraffic.work (bench/reference/worktree.py) has no "
+                f"sharded form (layout.ranks {ranks})")
         t = self.config["treecode"]
         w = worktree.count_work(
             self.x, self.x, theta=t["theta"], degree=t["degree"],
@@ -259,11 +305,13 @@ class SolveTraffic(Traffic):
 
         n = int(self.config["system"]["n"])
         count = int(self.params["point_sets"])
-        self.sets = [system_points(self.config, self.seed, 100 + k)[0]
+        self.sets = [system_points(self.config, _FIXED_SEED, 100 + k,
+                                   root=self.root)[0]
                      for k in range(count)]
         self.x = self.sets[0]
         self.charges = device_charges(self.seed, n, count)
-        self.plan_ = self.plan(self.sets[-1], shape_budget(self.config))
+        self.plan_ = self.plan(self.sets[-1],
+                               shape_budget(self.config, self.root))
         # The warm-up replans once, as every solve does.
         plan = self.plan_.replan(self.sets[0])
         jax.block_until_ready(plan.execute(self.charges[0]))
@@ -272,7 +320,8 @@ class SolveTraffic(Traffic):
         self.outputs, self.plan_s = [], 0.0
 
     def run_unit(self):
-        k = len(self.outputs) % len(self.sets)
+        # The window starts past the set that set-up replanned.
+        k = (len(self.outputs) + 1) % len(self.sets)
         with annotate("bench.replan"):
             t = time.perf_counter()
             plan = self.plan_.replan(self.sets[k])
@@ -310,7 +359,7 @@ class SolveTraffic(Traffic):
 KINDS = {"eval": EvalTraffic, "solve": SolveTraffic}
 
 
-def make_traffic(config, traffic, limits, seed) -> Traffic:
-    d = KINDS[traffic["kind"]](config, traffic, seed)
+def make_traffic(config, traffic, limits, seed, root=ROOT) -> Traffic:
+    d = KINDS[traffic["kind"]](config, traffic, seed, root)
     d.limits = limits
     return d

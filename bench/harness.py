@@ -5,10 +5,16 @@ cell or per-layer metric is data found by name:
 
     BENCHMARK.json             cells, metrics and their bounds
     bench/configs/<config>.json   the deployment, as run
+    bench/reference/systems/<generator>.py
+                                  the point generator `system.generator`
+                                  of a configuration names
     bench/traffic/<traffic>.json  the mix, read by bench/generator.py
     bench/limits/<cell>.json      the limits of the cell's comparison
     bench/metrics/<metric>.py     one reader per per-layer metric
     bench/peaks.json              the chips' published peaks
+
+A configuration's `layout.ranks` sets the ranks of the program's plan,
+one per chip: it has to be the cell's `chips`.
 
 The result is one JSON line on standard output, last; the numbers
 compared, each with its limit, are also the last lines on standard error.
@@ -42,6 +48,7 @@ class Cell:
     limits: dict
     end_to_end: list          # BENCHMARK.json metric entries of this cell
     per_layer: list
+    root: Path = ROOT         # the checkout that holds the cell's files
 
 
 def _for_cell(metrics: list, cell: str) -> list:
@@ -55,15 +62,20 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
         raise KeyError(f"no workload {name!r} in BENCHMARK.json")
     w = cells[name]
     conf = {c["name"]: c for c in spec["configs"]}[w["config"]]
+    config = json.loads((root / conf["file"]).read_text())
+    ranks = config.get("layout", {}).get("ranks")
+    if ranks != w["chips"]:
+        raise ValueError(f"{name} asks for {w['chips']} chip(s), but its "
+                         f"configuration {conf['name']!r} has layout.ranks "
+                         f"{ranks!r}")
     bench = root / "bench"
     return Cell(
-        name=name, chips=int(w["chips"]),
-        config=json.loads((root / conf["file"]).read_text()),
+        name=name, chips=int(w["chips"]), config=config,
         traffic=json.loads((bench / "traffic"
                             / f"{w['traffic']}.json").read_text()),
         limits=json.loads((bench / "limits" / f"{name}.json").read_text()),
         end_to_end=_for_cell(spec["end_to_end"], name),
-        per_layer=_for_cell(spec["per_layer"], name))
+        per_layer=_for_cell(spec["per_layer"], name), root=root)
 
 
 def enable_compile_cache() -> None:
@@ -158,7 +170,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     counter = CompileCounter()
 
     traffic = generator.make_traffic(cell.config, cell.traffic,
-                                     cell.limits, seed)
+                                     cell.limits, seed, cell.root)
     if traffic_hook is not None:
         traffic_hook(traffic)
     traffic.setup()

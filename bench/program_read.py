@@ -13,12 +13,6 @@ from __future__ import annotations
 import re
 from typing import Dict, Optional
 
-# How many of the costliest operations `trace_reduce.reduce_trace` lists
-# (its `top`).
-LISTED_OPS = 10
-# The program's Pallas kernels, by the names its kernel sites give them.
-PROGRAM_KERNELS = ("bltc_direct", "bltc_approx", "modified_charges")
-
 
 def plan_phases_s() -> Dict[str, float]:
     """Seconds of the host planner's phases in the window, from the
@@ -52,19 +46,12 @@ def _op(kernel: str):
 
 def kernel_device_s(trace, kernel: str) -> Optional[float]:
     """Device time in the traced window of the operations of the Pallas
-    kernel named `kernel`, among the costliest operations the reduction
-    lists. None where none is listed, or where the list may have dropped
-    some: it is full, and the window's Pallas time exceeds what the
-    program's named kernels in it account for by more than 1%."""
-    times = [t for name, t in trace.device_ops if _op(kernel).match(name)]
-    if not times:
-        return None
-    if len(trace.device_ops) >= LISTED_OPS:
-        named = sum(t for name, t in trace.device_ops
-                    if any(_op(k).match(name) for k in PROGRAM_KERNELS))
-        if trace.kernel_s - named > 0.01 * trace.kernel_s:
-            return None
-    return sum(times)
+    kernel named `kernel`, over every operation of the window (the
+    reduction's `op_totals`): all its calls, and a loop that holds it not
+    counted again. None where the window has none."""
+    times = [t for name, t in trace.op_totals.items()
+             if _op(kernel).match(name)]
+    return sum(times) if times else None
 
 
 def eval_kernel_work(traffic) -> Optional[dict]:
@@ -73,8 +60,6 @@ def eval_kernel_work(traffic) -> Optional[dict]:
     planned again under the same configuration: the count is a function
     of the plan alone."""
     from bench import generator
-    from repro.core.api import TreecodeSolver
 
-    plan = TreecodeSolver(generator.treecode_config(traffic.config)).plan(
-        traffic.x, nranks=1)
+    plan = generator.program_plan(traffic.config, traffic.x)
     return plan.stats().get("kernel_work")

@@ -29,10 +29,31 @@ def test_reference_matches_a_plain_double_loop(kernel, kappa):
         assert phi[k] == pytest.approx(p, rel=1e-12)
 
 
-def test_control_error_is_that_of_three_bf16_products():
-    x, q = generators.uniform_cloud(5000, seed=9)
+@pytest.mark.parametrize("vectors", [1, 3])
+def test_control_error_is_that_of_three_bf16_products(vectors):
+    # One charge vector or several: each reads the error of three
+    # bfloat16 products, not the program's own nor that of one.
+    x, _ = generators.uniform_cloud(5000, seed=9)
+    q = np.stack([generators.uniform_cloud(5000, seed=9 + k)[1]
+                  for k in range(vectors)], 1)
     rows = np.arange(0, 5000, 50)
     ref = direct.direct_f64(x, q, rows, kernel="coulomb")
     ctl = direct.control(x, q, rows, kernel="coulomb")
-    err = np.linalg.norm(ctl - ref) / np.linalg.norm(ref)
-    assert 3e-7 < err < 3e-5
+    for c in range(vectors):
+        err = np.linalg.norm(ctl[:, c] - ref[:, c]) / np.linalg.norm(ref[:, c])
+        assert 3e-7 < err < 3e-5
+
+
+def test_bf16_rounding_is_to_nearest_even():
+    one = np.float32(1.0)
+    ulp = np.float32(2.0 ** -7)               # bfloat16's step above 1
+    a = np.array([one + ulp / 2,              # a tie: down to the even 1
+                  one + 3 * ulp / 2,          # a tie: up to the even 1+2ulp
+                  one + ulp / 2 + np.float32(2.0 ** -20),
+                  -(one + ulp)], np.float32)
+    want = np.array([one, one + 2 * ulp, one + ulp, -(one + ulp)],
+                    np.float32)
+    assert np.array_equal(direct._bf16(a), want)
+    hi, lo = direct._split(a)
+    assert np.array_equal(hi, want)
+    assert np.all(np.abs(a - hi - lo) <= np.abs(a) * 2.0 ** -16)

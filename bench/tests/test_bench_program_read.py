@@ -1,7 +1,6 @@
 """The per-layer readers of the program's own instruments: the host
 planner's phases, kernels named by call site, and the count of kernel
 work."""
-import dataclasses
 import json
 import time
 
@@ -52,10 +51,14 @@ def test_plan_phases_split_the_replan(monkeypatch):
         dict(tree=1.0, lists=1.1))
 
 
-def _summary(device_ops, idle_gaps=()):
+def _summary(ops, idle_gaps=(), top=10):
+    """A reduced window whose operations take the given times; the
+    reduction lists the `top` costliest of them."""
     return trace_reduce.TraceSummary(
         window_s=10.0, busy_s=9.0, devices=1, kernel_s=8.0,
-        device_ops=list(device_ops), idle_gaps=list(idle_gaps))
+        op_totals=dict(ops),
+        device_ops=sorted(ops, key=lambda o: -o[1])[:top],
+        idle_gaps=list(idle_gaps))
 
 
 def test_kernel_device_s_reads_the_kernel_named_by_its_site():
@@ -73,23 +76,34 @@ def test_kernel_device_s_reads_the_kernel_named_by_its_site():
     assert program_read.kernel_device_s(old, "bltc_direct") is None
 
 
-def test_kernel_device_s_refuses_a_list_that_may_have_dropped_ops():
-    # The reduction lists the ten costliest operations. When the list is
-    # full and the window's Pallas time is not accounted for by the
-    # program's named kernels in it, more of the kernel may lie below.
-    fill = [(f"%fusion.{i} = f32[8] fusion()", 0.001) for i in range(7)]
-    ops = [("%bltc_direct.7 = custom-call()", 5.0),
-           ("%bltc_approx.8 = custom-call()", 2.0),
-           ("%modified_charges.2 = custom-call()", 0.05)] + fill
-    full = trace_reduce.TraceSummary(
-        window_s=10.0, busy_s=9.0, devices=1, kernel_s=7.05 + 0.06,
-        device_ops=ops, idle_gaps=[])
-    assert program_read.kernel_device_s(full, "bltc_direct") == 5.0
-    dropped = dataclasses.replace(full, kernel_s=7.05 + 0.5)
-    assert program_read.kernel_device_s(dropped, "bltc_direct") is None
-    # The same window with room left in the list holds every operation.
-    short = dataclasses.replace(dropped, device_ops=ops[:9])
-    assert program_read.kernel_device_s(short, "bltc_direct") == 5.0
+def test_kernel_device_s_reads_past_the_ten_costliest():
+    # The window has more operations than the breakdown lists; the kernel
+    # is read from the totals of all of them, whatever the list keeps.
+    fill = [(f"%fusion.{i} = f32[8] fusion()", 0.5) for i in range(12)]
+    ops = fill + [("%bltc_direct.7 = custom-call()", 5.0),
+                  ("%bltc_approx.8 = custom-call()", 0.2),
+                  ("%modified_charges.2 = custom-call()", 0.05)]
+    s = _summary(ops)
+    assert len(s.device_ops) == 10 and len(s.op_totals) == 15
+    assert "%bltc_approx.8 = custom-call()" not in dict(s.device_ops)
+    assert program_read.kernel_device_s(s, "bltc_direct") == 5.0
+    assert program_read.kernel_device_s(s, "bltc_approx") == 0.2
+    assert program_read.kernel_device_s(s, "modified_charges") == 0.05
+
+
+def test_kernel_device_s_sums_a_kernel_split_into_calls():
+    # A kernel run as many calls, each below the ten costliest, inside
+    # loops that the trace lists too: every call counts once, the loops
+    # not at all.
+    calls = [(f"%bltc_direct.{i} = f32[40,1,2048] custom-call()", 0.25)
+             for i in range(20)]
+    loops = [(f"%while.{i} = (s32[]) while()", 0.9) for i in range(10)]
+    s = _summary(calls + loops + [("%bltc_approx.99 = custom-call()", 1.0)])
+    assert not any(n.startswith("%bltc_direct") for n, _ in s.device_ops)
+    assert program_read.kernel_device_s(s, "bltc_direct") \
+        == pytest.approx(20 * 0.25)
+    assert program_read.kernel_device_s(s, "bltc_approx") == 1.0
+    assert program_read.kernel_device_s(s, "bltc_missing") is None
 
 
 def _ctx(trace=None, layer=None, traffic=None):

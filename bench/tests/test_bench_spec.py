@@ -37,6 +37,9 @@ def test_config(conf):
     data = json.loads((ROOT / conf["file"]).read_text())
     assert data["name"] == conf["name"]
     assert data["reduced"] == conf["reduced"]
+    generator_file = f"{data['system']['generator']}.py"
+    assert (ROOT / "bench" / "reference" / "systems"
+            / generator_file).exists()
     assert any(w["config"] == conf["name"] for w in SPEC["workloads"])
 
 

@@ -1,5 +1,6 @@
 """From a profiler trace to device metrics: busy and idle time, kernel
-time, the costliest operations and the longest idle gaps.
+time, the time of every operation, the costliest operations and the
+longest idle gaps.
 
 The trace is JAX's own (`jax.profiler.start_trace`); `load_profile`
 reads its `.xplane.pb` with `jax.profiler.ProfileData`. Device operations
@@ -20,10 +21,13 @@ from typing import Dict, List, Optional, Tuple
 WINDOW_SPAN = "bench.window"
 _DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 _OPS_LINES = ("XLA Ops",)
-# Words that mark a Pallas (Mosaic) kernel in an operation's name or
-# metadata.
-_PALLAS_WORDS = ("tpu_custom_call", "custom-call", "custom_call", "pallas",
-                 "mosaic")
+# An operation's own instruction, in its HLO text
+# ``%name.7 = f32[8,128]{1,0:T(8,128)} custom-call(...)``: the first
+# lower-case word after the `=` that opens an argument list (the layout's
+# tiles, ``T(8,128)``, are upper case and follow no space).
+_OPCODE = re.compile(r"=.*?\s([a-z][a-z0-9_-]*)\(")
+# The custom-call target of a Pallas (Mosaic) kernel on a TPU.
+_MOSAIC_TARGET = "tpu_custom_call"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -35,8 +39,13 @@ class Event:
 
 
 def is_pallas(ev: Event) -> bool:
-    text = f"{ev.name} {ev.meta}".lower()
-    return any(w in text for w in _PALLAS_WORDS)
+    """Whether the operation is a Pallas (Mosaic) kernel: its own
+    instruction is the TPU's custom call. An operation that only takes a
+    kernel's output as an operand (``fusion(... %custom-call.56 ...)``)
+    is not one, nor is a loop whose body holds one."""
+    m = _OPCODE.search(ev.name) or _OPCODE.search(ev.meta)
+    return m is not None and m.group(1) == "custom-call" \
+        and _MOSAIC_TARGET in f"{ev.name} {ev.meta}"
 
 
 def _clip(ev: Event, lo: float, hi: float) -> float:
@@ -78,7 +87,8 @@ class TraceSummary:
     busy_s: float                  # mean over devices
     devices: int
     kernel_s: float                # Pallas kernels, mean over devices
-    device_ops: List[Tuple[str, float]]
+    op_totals: Dict[str, float]    # every operation name, mean over devices
+    device_ops: List[Tuple[str, float]]    # the costliest of `op_totals`
     idle_gaps: List[Tuple[str, float]]
 
     @property
@@ -114,8 +124,8 @@ def reduce_trace(device_events: Dict[int, List[Event]],
     ops = sorted(by_op.items(), key=lambda kv: -kv[1])[:top]
     gaps = sorted(gaps, key=lambda g: -g[1])[:top]
     return TraceSummary(window_s=hi - lo, busy_s=busy / nd, devices=nd,
-                        kernel_s=kernel / nd, device_ops=ops,
-                        idle_gaps=gaps)
+                        kernel_s=kernel / nd, op_totals=dict(by_op),
+                        device_ops=ops, idle_gaps=gaps)
 
 
 def load_profile(log_dir: str):
